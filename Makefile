@@ -107,10 +107,12 @@ trace-smoke:
 	JAX_PLATFORMS=cpu $(PY) scripts/trace_smoke.py \
 		--trace-out /tmp/trace_smoke.json
 
-# Perf-regression gate (specs/slo.md, ADR-014): judge the committed
-# BENCH_r*.json + bench_cache.json trajectory — exits non-zero with a
-# readable table when any tracked wall (extend, repair, node-path,
-# transfer) regresses beyond threshold vs its median±MAD baseline.
+# Perf-regression gate (specs/slo.md, ADR-014): judge the tracked series
+# vs their median±MAD baselines; exits non-zero with a readable table on
+# a regression. The bench walls (extend, repair, node-path, transfer,
+# fused, xor) come from BENCH_r*.json rounds, none of which is committed
+# since PR 21: they are inert until the benchmark PR feeds them from the
+# driver's PERF_LEDGER.jsonl. The storm/scenario/soak series still gate.
 # Pure ledger math, never touches the accelerator.
 bench-gate:
 	$(PY) bench.py --check-regressions
@@ -253,14 +255,14 @@ xor-smoke:
 	JAX_PLATFORMS=cpu $(PY) scripts/xor_smoke.py
 
 # The ADR-019 step-change configs alone on the real chip: fused
-# roots-only vs the XLA roots path vs native at k ∈ {64, 32}; writes
-# the fused_ms_per_square_k64 series `make bench-gate` judges.
+# roots-only vs the XLA roots path vs native at k ∈ {64, 32}; prints
+# one JSON line (nothing persists it since PR 21).
 bench-fused:
 	$(PY) bench.py --fused-kernels
 
 # The ADR-024 A/B alone: sparse XOR schedule vs the dense bit-matmul
-# inside the same fused hash pipeline at k ∈ {64, 32}; writes the
-# xor_schedule_ms_per_square_k64 series `make bench-gate` judges.
+# inside the same fused hash pipeline at k ∈ {64, 32}; prints one JSON
+# line (nothing persists it since PR 21).
 # Add --write-table to refresh config/xor_schedule.json.
 bench-xor:
 	$(PY) bench.py --xor-schedule

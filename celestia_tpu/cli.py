@@ -135,7 +135,7 @@ def cmd_start(args):
     # calibrated auto crossover (app/calibration.py, ADR-012): load the
     # persisted per-k table when present; measure + persist a fresh one
     # when configured or asked (--calibrate-crossover refreshes a stale
-    # table, e.g. after the tunnel/hardware changed)
+    # table, e.g. after the hardware changed)
     from celestia_tpu.app.calibration import CrossoverTable, crossover_path
 
     cal_path = crossover_path(home)
@@ -147,16 +147,7 @@ def cmd_start(args):
         node.app.calibrate_crossover(persist_path=cal_path)
     # resolve + log the live backend up front so the operator sees what
     # this node will actually run on the hot path
-    live = node.app.resolve_extend_backend(
-        node.app.gov_square_size_upper_bound()
-    )
-    if live == "tpu":
-        # device blob arena: mempool blob bytes stage in HBM at CheckTx,
-        # so proposals assemble squares on device (metadata-only upload)
-        node.app.enable_blob_pool()
-        # share-serving stays sliced: retain committed EDS handles
-        # device-resident so a DAS sample moves one row, not 32 MB
-        node.extend_blocks = True
+    live = node.boot_extend_backend()
     server = RpcServer(node, port=args.port)
     server.start()
     # synthetic DAS prober (node/prober.py): black-box samples through

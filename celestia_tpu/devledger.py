@@ -70,6 +70,42 @@ from celestia_tpu import tracing
 from celestia_tpu import telemetry
 
 
+# jax.monitoring events of the persistent compilation cache -> counters.
+# jax records a miss only when it writes the program to the cache, i.e.
+# for programs over jax_persistent_cache_min_compile_time_secs.
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "xla_compile_cache_hit_total",
+    "/jax/compilation_cache/cache_misses": "xla_compile_cache_miss_total",
+}
+# the entry each thread is compiling, read by the one process-wide
+# cache-event listener (jax.monitoring listeners cannot be removed, so
+# there is one per process, not one per ledger)
+_compiling = threading.local()
+_listener_installed = False
+
+
+def install_monitoring() -> None:
+    """Count jax persistent-compilation-cache hits and misses (ADR-011's
+    `.jax_cache`) via the jax.monitoring event stream, attributed to
+    the entry currently compiling, or "other" for a program built
+    outside an instrumented builder. Idempotent."""
+    global _listener_installed
+    from jax import monitoring
+
+    with ledger._lock:
+        if _listener_installed:
+            return
+        _listener_installed = True
+
+    def _listener(event, *args, **kwargs):
+        name = _CACHE_EVENTS.get(event)
+        if name is not None:
+            telemetry.metrics.incr_counter(
+                name, entry=getattr(_compiling, "entry", None) or "other")
+
+    monitoring.register_event_listener(_listener)
+
+
 class RetraceError(RuntimeError):
     """A post-warmup recompile of a known jitted entry under strict
     mode — the geometry churn ADR-011 says must never reach steady
@@ -115,8 +151,6 @@ class DeviceLedger:
         self._warm = False
         self._strict = os.environ.get(
             "CELESTIA_STRICT_RETRACE", "") not in ("", "0")
-        self._monitoring_installed = False
-        self._tls = threading.local()
         # -- byte-ledger state --
         self._owners: list[tuple[str, object]] = []  # (name, weak ref)
         # -- busy-timeline state --
@@ -215,15 +249,15 @@ class DeviceLedger:
         return call
 
     def _timed_compile(self, entry: str, key: str, fn, args, kwargs):
-        self._install_monitoring()
-        self._tls.entry = entry
+        install_monitoring()
+        _compiling.entry = entry
         t0 = time.perf_counter()
         sp = tracing.span("xla.compile", entry=entry, key=key)
         try:
             with sp:
                 out = fn(*args, **kwargs)
         finally:
-            self._tls.entry = None
+            _compiling.entry = None
         wall = time.perf_counter() - t0
         with self._lock:
             self._compiles[entry] += 1
@@ -237,30 +271,6 @@ class DeviceLedger:
         except Exception:  # noqa: BLE001
             pass
         return out
-
-    def _install_monitoring(self) -> None:
-        """Attribute jax persistent-compilation-cache hits (ADR-011's
-        `.jax_cache`) to the entry currently compiling, via the
-        jax.monitoring event stream when this jax version has one."""
-        with self._lock:
-            if self._monitoring_installed:
-                return
-            self._monitoring_installed = True
-        try:
-            from jax import monitoring
-
-            def _listener(event, *args, **kwargs):
-                if "compilation_cache" not in str(event) or \
-                        "hit" not in str(event):
-                    return
-                ent = getattr(self._tls, "entry", None)
-                if ent:
-                    telemetry.metrics.incr_counter(
-                        "xla_compile_cache_hit_total", entry=ent)
-
-            monitoring.register_event_listener(_listener)
-        except Exception:  # noqa: BLE001 — older jax: no event stream
-            pass
 
     def begin_warmup(self) -> None:
         """Re-enter warmup (a new scenario run / test phase): retraces
@@ -487,7 +497,7 @@ def _provenance() -> tuple:
 
 
 def runtime_provenance() -> dict:
-    """Host/runtime identity stamped into bench_cache entries, `.ctts`
+    """Host/runtime identity stamped into bench results, `.ctts`
     recording headers, and scenario reports so longitudinal series are
     comparable across hosts (computed once per process)."""
     return dict(_provenance())

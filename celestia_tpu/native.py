@@ -1,14 +1,21 @@
 """ctypes bindings for the native (C++) host runtime in native/.
 
-The library is compiled on first use with g++ -O3 (no pip/pkg deps; the
-toolchain is part of the base image) and cached under native/build/.
-Falls back cleanly — callers check `available()` and use the numpy host
-path (celestia_tpu.da) when the toolchain is missing.
+The library is compiled on first use with g++ -O3 -march=native (no
+pip/pkg deps; the toolchain is part of the base image) from the
+committed native/*.cc sources only. The built file's name carries a
+hash of those sources, the compiler flags and the host fingerprint
+(ops._machine_fingerprint), so a stale library, or one a copy of this
+checkout brought from a CPU with other features, is never loaded: a
+missing key means a fresh build. Falls back cleanly — callers check
+`available()` and use the numpy host path (celestia_tpu.da) when the
+toolchain is missing.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import os
 import pathlib
 import subprocess
 
@@ -18,7 +25,8 @@ from celestia_tpu.appconsts import SHARE_SIZE
 
 _NATIVE_DIR = pathlib.Path(__file__).resolve().parent.parent / "native"
 _BUILD_DIR = _NATIVE_DIR / "build"
-_LIB_PATH = _BUILD_DIR / "libcelestia_native.so"
+_SOURCES = (_NATIVE_DIR / "leopard.cc", _NATIVE_DIR / "nmt.cc")
+_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC")
 
 _lib = None
 _load_error: str | None = None
@@ -26,14 +34,32 @@ _load_error: str | None = None
 NMT_NODE_SIZE = 90
 
 
-def _build() -> None:
+def build_key(sources=_SOURCES) -> str:
+    """Digest of the source bytes, the compiler flags and the host
+    fingerprint: the library built from exactly these, on this kind of
+    host, is the only one that may load."""
+    from celestia_tpu.ops import _machine_fingerprint
+
+    h = hashlib.sha256()
+    for path in sources:
+        h.update(pathlib.Path(path).read_bytes())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_machine_fingerprint().encode())
+    return h.hexdigest()[:16]
+
+
+def _lib_path() -> pathlib.Path:
+    return _BUILD_DIR / f"libcelestia_native-{build_key()}.so"
+
+
+def _build(lib_path: pathlib.Path) -> None:
     _BUILD_DIR.mkdir(exist_ok=True)
-    sources = [str(_NATIVE_DIR / "leopard.cc"), str(_NATIVE_DIR / "nmt.cc")]
-    cmd = [
-        "g++", "-O3", "-march=native", "-shared", "-fPIC",
-        "-o", str(_LIB_PATH), *sources,
-    ]
+    # build beside the target and rename: concurrent processes never
+    # load a half-written library
+    tmp = lib_path.with_suffix(f".{os.getpid()}.tmp")
+    cmd = ["g++", *_FLAGS, "-o", str(tmp), *map(str, _SOURCES)]
     subprocess.run(cmd, check=True, capture_output=True)
+    os.replace(tmp, lib_path)
 
 
 def _load():
@@ -41,12 +67,10 @@ def _load():
     if _lib is not None or _load_error is not None:
         return _lib
     try:
-        sources_mtime = max(
-            p.stat().st_mtime for p in (_NATIVE_DIR / "leopard.cc", _NATIVE_DIR / "nmt.cc")
-        )
-        if not _LIB_PATH.exists() or _LIB_PATH.stat().st_mtime < sources_mtime:
-            _build()
-        lib = ctypes.CDLL(str(_LIB_PATH))
+        lib_path = _lib_path()
+        if not lib_path.exists():
+            _build(lib_path)
+        lib = ctypes.CDLL(str(lib_path))
         lib.leo_encode.argtypes = [
             ctypes.c_int, ctypes.c_size_t, ctypes.c_char_p, ctypes.c_char_p,
         ]

@@ -256,6 +256,20 @@ class Node:
 
     MAX_FRAUD_PROOFS_PER_HEIGHT = 4
 
+    def boot_extend_backend(self) -> str:
+        """Resolve the extend backend at the governance square cap and,
+        when it is the device, turn on what a device node serves with:
+        the blob arena (mempool blob bytes stage in HBM at CheckTx, so
+        proposals assemble squares on device) and device-resident EDS
+        retention (a DAS sample moves one row, not 32 MB). Returns the
+        live backend. `cli start` boots through here."""
+        live = self.app.resolve_extend_backend(
+            self.app.gov_square_size_upper_bound())
+        if live == "tpu":
+            self.app.enable_blob_pool()
+            self.extend_blocks = True
+        return live
+
     def add_fraud_proof(self, height: int, dah_hash: bytes, wire: dict,
                         force: bool = False) -> bool:
         """Store a VERIFIED fraud proof. Returns False when already
@@ -451,7 +465,8 @@ class Node:
                     self._eds_cache.put(block.height, eds)
                 self._persist_block_eds(block.height, eds)
             except Exception as e:  # noqa: BLE001 — retention is a cache
-                log.info("eds retention failed", error=str(e))
+                log.warn("eds retention failed",
+                         error=f"{type(e).__name__}: {e}")
 
         for i, raw in enumerate(proposal.txs):
             key = tx_hash(raw)
@@ -858,8 +873,9 @@ class Node:
                     # hashing on the restart path too
                     levels = self.store.read_levels(height)
             except Exception as exc:  # device trouble must not fail DAS
-                log.info("device prover seeding failed; host fallback",
-                         height=height, error=str(exc))
+                log.warn("device prover seeding failed; host fallback",
+                         height=height,
+                         error=f"{type(exc).__name__}: {exc}")
                 levels = None
             while len(self._prover_cache) >= self._PROVER_CACHE_HEIGHTS:
                 self._prover_cache.pop(next(iter(self._prover_cache)))

@@ -40,34 +40,34 @@ def _machine_fingerprint() -> str:
 
 
 def enable_compile_cache() -> str:
-    """Point JAX's persistent compilation cache at a repo-local,
-    MACHINE-PARTITIONED directory (idempotent; env wins if already set).
+    """Turn on JAX's persistent compilation cache (idempotent) and
+    return its directory.
 
-    The repair sweep program at k=128 costs tens of seconds to compile
-    cold; a warmed cache turns every later process start — node restart,
-    bench run, driver dryrun — into a disk load. Partitioning by the
-    host fingerprint (_machine_fingerprint) keeps one box's AOT
-    executables from ever loading on a box with different CPU features,
-    which is a hard crash, not a recompile. Returns the cache dir in
-    use."""
+    `JAX_COMPILATION_CACHE_DIR`, when set, is the directory, and
+    nothing here configures another. Otherwise the cache lives at a
+    fixed path inside the checkout, `.jax_cache/<host fingerprint>`,
+    partitioned by _machine_fingerprint so one box's AOT executables
+    never load on a box with different CPU features (a hard crash, not
+    a recompile). The repair sweep and the k=128 extend cost tens of
+    seconds to compile cold; a warm cache turns every later process
+    start into a disk load.
+
+    The Pallas kernels' serialized Mosaic body keeps its source
+    locations, and that body is part of the cache key, so a kernel
+    compiled in one checkout missed the cache in another. Source paths
+    are therefore made relative to the checkout root."""
+    import re
+
     import jax
 
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not cache_dir:
-        cache_dir = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-            ".jax_cache",
-            _machine_fingerprint(),
-        )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # 3 s threshold: only the expensive programs (device-path k=128
-        # extends, repair sweeps, sharded steps) are worth persisting,
-        # and every write/read is exposure to an intermittent jaxlib
-        # executable-(de)serialization segfault observed twice under the
-        # long concurrent suite — persist an order of magnitude fewer
-        # programs, keep the wins that matter
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 3.0)
-    except Exception:  # noqa: BLE001 — older jax without the knobs
-        pass
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        root, ".jax_cache", _machine_fingerprint())
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(root + os.sep))
+    # 3 s threshold: only the expensive programs (device-path k=128
+    # extends, repair sweeps, sharded steps) are worth persisting
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 3.0)
     return cache_dir

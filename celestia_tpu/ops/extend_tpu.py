@@ -315,11 +315,12 @@ def _roots_of(shares: jnp.ndarray, m2: jnp.ndarray,
 
 
 def extend_and_root(
-    shares: jnp.ndarray, m2: jnp.ndarray
+    shares: jnp.ndarray, m2: jnp.ndarray, fused: bool | None = None
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """(k, k, 512) uint8 -> (eds (2k,2k,512), row_roots (2k,90),
-    col_roots (2k,90), dah_hash (32,)). m2 = rs_tpu.encode_bit_matrix(k)."""
-    eds, row_roots, col_roots = _roots_of(shares, m2)
+    col_roots (2k,90), dah_hash (32,)). m2 = rs_tpu.encode_bit_matrix(k);
+    fused as in _roots_of."""
+    eds, row_roots, col_roots = _roots_of(shares, m2, fused=fused)
     dah = merkle_root_pow2(jnp.concatenate([row_roots, col_roots], axis=0))
     return eds, row_roots, col_roots, dah
 
@@ -915,14 +916,16 @@ def _assembled_roots_traced(arena, host_shares, host_pos, host_row,
     return np.asarray(rows), np.asarray(cols)
 
 
-def extend_and_root_batched(shares: jnp.ndarray, m2: jnp.ndarray):
+def extend_and_root_batched(shares: jnp.ndarray, m2: jnp.ndarray,
+                            fused: bool | None = None):
     """(B, k, k, 512) -> batched (eds, row_roots, col_roots, dah).
 
     The multi-block form: a node that is catching up (state sync / block
     replay) or serving many proposals extends B squares at once; B is the
     data-parallel axis when sharded over a mesh (see __graft_entry__).
+    fused as in _roots_of.
     """
-    return jax.vmap(lambda s: extend_and_root(s, m2))(shares)
+    return jax.vmap(lambda s: extend_and_root(s, m2, fused))(shares)
 
 
 def _rows_cols_only(shares: jnp.ndarray, m2: jnp.ndarray,

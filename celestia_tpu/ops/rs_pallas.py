@@ -55,7 +55,7 @@ from celestia_tpu.ops.sha256_jax import pad_tail
 #   acc int32 (1024, TN) 4 MB, out (128, TN) 128 KB  ->  ~6.5 MB.
 # The fused hash stage adds (ADR-019's budget table):
 #   message words u32 (144, 2k) 147 KB at k=128, schedule + 8 state
-#   lanes ~300 KB transient, digests out (k, 2, 8) 8 KB  ->  ~7.0 MB.
+#   lanes ~300 KB transient, digests out (1, 8, 2k) 8 KB  ->  ~7.0 MB.
 _TILE_N = 1024
 
 # Below this square size the (8k, 8k) operands are too small to tile the
@@ -100,33 +100,45 @@ def _encode_math(x, m2):
     return packed.astype(jnp.uint8)
 
 
+def _const_rows(vals, n_lanes: int) -> jnp.ndarray:
+    """(len(vals), n_lanes) uint32 rows, each row the broadcast of one
+    Python int. Built from scalars with iota/where: Mosaic refuses a
+    kernel that captures an array constant, so message bytes that are
+    the same for every cell (parity prefix, SHA tail) are spelled this
+    way inside the kernels."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (len(vals), n_lanes), 0)
+    out = jnp.zeros((len(vals), n_lanes), dtype=jnp.uint32)
+    for j, v in enumerate(vals):
+        if v:
+            out = jnp.where(row == j, np.uint32(v), out)
+    return out
+
+
 def _leaf_digest_math(cells, prefix30):
     """SHA-256 leaf digests of whole cells, entirely in registers/VMEM.
 
     cells: (k, T) uint8, T a multiple of SHARE_SIZE — nc = T/512 complete
-    cells per row. prefix30: (30, k·nc) uint32 byte lanes (0x00 ‖ 29-byte
-    namespace per cell). Returns (k, nc, 8) uint32 digest words.
+    cells per row. prefix30: (30, nc·k) uint32 byte lanes (0x00 ‖ 29-byte
+    namespace per cell). Returns 8 digest-word vectors of shape (nc·k,).
 
-    The byte->word repack keeps cells on the LANE axis (the sha256_pallas
-    layout contract): message bytes land as (576, k·nc), fold to
-    (144, 4, k·nc), and the big-endian combine is a sublane reduction the
-    VPU vectorizes across all cell lanes at once."""
+    Lanes are ordered (cell c, row r) -> c·k + r: each cell column is a
+    (k, 512) slice transposed to byte-position-major (512, k), so the
+    message bytes land as (576, nc·k), fold to (144, 4, nc·k), and the
+    big-endian combine is a sublane reduction the VPU vectorizes across
+    all cell lanes at once (the sha256_pallas layout contract)."""
     from celestia_tpu.ops.sha256_pallas import _sha_core
 
     k, t = cells.shape
     nc = t // SHARE_SIZE
     n_lanes = k * nc
-    # (k, nc, 512) -> byte-position-major (512, k·nc)
-    body = (
-        cells.reshape(k, nc, SHARE_SIZE)
-        .transpose(2, 0, 1)
-        .reshape(SHARE_SIZE, n_lanes)
-        .astype(jnp.uint32)
-    )
-    tail = jnp.broadcast_to(
-        jnp.asarray(_LEAF_TAIL, dtype=jnp.uint32)[:, None],
-        (len(_LEAF_TAIL), n_lanes),
-    )
+    body = jnp.concatenate(
+        [
+            cells[:, c * SHARE_SIZE:(c + 1) * SHARE_SIZE].astype(jnp.uint32).T
+            for c in range(nc)
+        ],
+        axis=1,
+    )  # (512, nc·k)
+    tail = _const_rows([int(v) for v in _LEAF_TAIL], n_lanes)
     msg = jnp.concatenate([prefix30, body, tail], axis=0)  # (576, lanes)
     b = msg.reshape(_LEAF_WORDS, 4, n_lanes)
     words = (
@@ -135,29 +147,24 @@ def _leaf_digest_math(cells, prefix30):
         | (b[:, 2] << np.uint32(8))
         | b[:, 3]
     )  # (144, lanes) big-endian, 9 blocks
-    state = _sha_core(words)  # 8 x (lanes,)
-    return jnp.stack(state).reshape(8, k, nc).transpose(1, 2, 0)
+    return _sha_core(words)
 
 
 def _parity_prefix(n_lanes: int) -> jnp.ndarray:
-    return jnp.broadcast_to(
-        jnp.asarray(_PARITY_PREFIX, dtype=jnp.uint32)[:, None],
-        (1 + NAMESPACE_SIZE, n_lanes),
-    )
+    return _const_rows([int(v) for v in _PARITY_PREFIX], n_lanes)
 
 
-def _ns_prefix(ns_pad, k: int, nc: int) -> jnp.ndarray:
-    """(k, nc, NS_PAD) uint8 padded namespaces -> (30, k·nc) uint32
-    message-prefix lanes (0x00 ‖ ns), cells on the lane axis to match
-    _leaf_digest_math's byte layout."""
-    n_lanes = k * nc
-    nsb = (
-        ns_pad.transpose(2, 0, 1)
-        .reshape(NS_PAD, n_lanes)[:NAMESPACE_SIZE]
-        .astype(jnp.uint32)
-    )
-    zero = jnp.zeros((1, n_lanes), dtype=jnp.uint32)
+def _ns_prefix(ns_lanes) -> jnp.ndarray:
+    """(NS_PAD, nc·k) uint8 lane-major namespaces -> (30, nc·k) uint32
+    message-prefix lanes (0x00 ‖ ns)."""
+    nsb = ns_lanes[:NAMESPACE_SIZE].astype(jnp.uint32)
+    zero = jnp.zeros((1, nsb.shape[1]), dtype=jnp.uint32)
     return jnp.concatenate([zero, nsb], axis=0)
+
+
+def _store_digests(d_ref, state) -> None:
+    for i in range(8):
+        d_ref[0, i, :] = state[i]
 
 
 def _encode_kernel(x_ref, m2_ref, o_ref):
@@ -167,20 +174,53 @@ def _encode_kernel(x_ref, m2_ref, o_ref):
 def _fused_kernel(x_ref, m2_ref, o_ref, d_ref):
     """Encode + leaf-hash in ONE pass: the parity tile never leaves VMEM
     between the pack stage and the SHA rounds. Every produced cell is a
-    parity cell, so its namespace is the baked constant."""
+    parity cell, so its namespace is a kernel constant."""
     packed = _encode_math(x_ref[...], m2_ref[...])
     o_ref[...] = packed
-    k, t = packed.shape
-    nc = t // SHARE_SIZE
-    d_ref[...] = _leaf_digest_math(packed, _parity_prefix(k * nc))
+    n_lanes = packed.shape[1] // SHARE_SIZE * packed.shape[0]
+    _store_digests(d_ref, _leaf_digest_math(packed, _parity_prefix(n_lanes)))
 
 
 def _leaf_kernel(x_ref, ns_ref, d_ref):
     """Leaf-hash EXISTING cells (Q0) with per-cell namespaces."""
-    x = x_ref[...]
-    k, t = x.shape
-    nc = t // SHARE_SIZE
-    d_ref[...] = _leaf_digest_math(x, _ns_prefix(ns_ref[...], k, nc))
+    _store_digests(d_ref, _leaf_digest_math(x_ref[...], _ns_prefix(ns_ref[0])))
+
+
+# Digests leave the kernels lane-major, one (8, nct·k) block per grid
+# step: Mosaic tiles a block's last two dimensions by (8, 128) unless
+# they span the whole array, so the grid index rides a leading axis
+# and the (tiny) relayout to (k, nc, 8) happens in XLA.
+def _digest_spec(pl, k: int, nct: int):
+    return pl.BlockSpec((1, 8, nct * k), lambda i: (i, 0, 0))
+
+
+def _digest_shape(k: int, n: int):
+    grid, tile = _grid_tile(n)
+    return jax.ShapeDtypeStruct((grid, 8, tile // SHARE_SIZE * k), jnp.uint32)
+
+
+def _digests_to_cells(d, k: int) -> jnp.ndarray:
+    """(grid, 8, nct·k) kernel digests -> (k, nc, 8): lane c·k + r of
+    grid step i is cell (r, i·nct + c)."""
+    grid, _, lanes = d.shape
+    nct = lanes // k
+    return (
+        d.reshape(grid, 8, nct, k)
+        .transpose(3, 0, 2, 1)
+        .reshape(k, grid * nct, 8)
+    )
+
+
+def _ns_to_lanes(ns_pad, k: int, tile: int):
+    """(k, nc, NS_PAD) padded namespaces -> (grid, NS_PAD, nct·k) in the
+    kernel's lane order (inverse of _digests_to_cells' cell mapping)."""
+    nct = tile // SHARE_SIZE
+    grid = ns_pad.shape[1] // nct
+    return (
+        ns_pad.reshape(k, grid, nct, NS_PAD)
+        .transpose(1, 3, 2, 0)
+        .reshape(grid, NS_PAD, nct * k)
+    )
 
 
 def _grid_tile(n: int) -> tuple[int, int]:
@@ -223,11 +263,11 @@ def _fused_call(k: int, n: int, interpret: bool):
         ],
         out_specs=[
             pl.BlockSpec((k, tile), lambda i: (0, i)),
-            pl.BlockSpec((k, nct, 8), lambda i: (0, i, 0)),
+            _digest_spec(pl, k, nct),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, n), jnp.uint8),
-            jax.ShapeDtypeStruct((k, n // SHARE_SIZE, 8), jnp.uint32),
+            _digest_shape(k, n),
         ],
         interpret=interpret,
     )
@@ -245,10 +285,10 @@ def _leaf_call(k: int, n: int, interpret: bool):
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((k, tile), lambda i: (0, i)),
-            pl.BlockSpec((k, nct, NS_PAD), lambda i: (0, i, 0)),
+            pl.BlockSpec((1, NS_PAD, nct * k), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((k, nct, 8), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, n // SHARE_SIZE, 8), jnp.uint32),
+        out_specs=_digest_spec(pl, k, nct),
+        out_shape=_digest_shape(k, n),
         interpret=interpret,
     )
 
@@ -281,7 +321,8 @@ def encode2d_hash(x2: jnp.ndarray, m2: jnp.ndarray, interpret: bool = False):
     — the NMT leaf digest of every produced cell, computed before the
     parity tile ever leaves VMEM (ADR-019)."""
     k, n = x2.shape
-    return _fused_call(k, n, interpret)(x2, m2.astype(jnp.int8))
+    parity, d = _fused_call(k, n, interpret)(x2, m2.astype(jnp.int8))
+    return parity, _digests_to_cells(d, k)
 
 
 def pad_namespaces(ns_cells: jnp.ndarray) -> jnp.ndarray:
@@ -297,7 +338,8 @@ def leaf_digests2d(x2: jnp.ndarray, ns_pad: jnp.ndarray,
     """NMT leaf digests of EXISTING cells: (k, N) uint8 cell bytes +
     (k, N/512, NS_PAD) padded namespaces -> (k, N/512, 8) uint32."""
     k, n = x2.shape
-    return _leaf_call(k, n, interpret)(x2, ns_pad)
+    d = _leaf_call(k, n, interpret)(x2, _ns_to_lanes(ns_pad, k, _grid_tile(n)[1]))
+    return _digests_to_cells(d, k)
 
 
 # ------------------------------------------------------------------ #
@@ -327,11 +369,13 @@ def encode2d_hash_reference(x2, m2, tile=None):
     for i in range(grid):
         xt = x2[:, i * tile:(i + 1) * tile]
         p = _encode_math(xt, m2i)
-        parity.append(p)
-        digests.append(_leaf_digest_math(p, _parity_prefix(k * (tile // SHARE_SIZE))))
+        parity.append(np.asarray(p))
+        digests.append(jnp.stack(
+            _leaf_digest_math(p, _parity_prefix(k * (tile // SHARE_SIZE)))
+        ))
     return (
-        np.concatenate([np.asarray(p) for p in parity], axis=1),
-        np.concatenate([np.asarray(d) for d in digests], axis=1),
+        np.concatenate(parity, axis=1),
+        np.asarray(_digests_to_cells(jnp.stack(digests), k)),
     )
 
 
@@ -346,15 +390,12 @@ def leaf_digests2d_reference(x2, ns_pad, tile=None):
     else:
         assert n % tile == 0 and tile % SHARE_SIZE == 0
         grid = n // tile
-    nct = tile // SHARE_SIZE
+    ns_lanes = _ns_to_lanes(ns_pad, k, tile)
     out = []
     for i in range(grid):
         xt = x2[:, i * tile:(i + 1) * tile]
-        nst = ns_pad[:, i * nct:(i + 1) * nct]
-        out.append(np.asarray(
-            _leaf_digest_math(xt, _ns_prefix(nst, k, nct))
-        ))
-    return np.concatenate(out, axis=1)
+        out.append(jnp.stack(_leaf_digest_math(xt, _ns_prefix(ns_lanes[i]))))
+    return np.asarray(_digests_to_cells(jnp.stack(out), k))
 
 
 def extend_square(q0: jnp.ndarray, m2: jnp.ndarray, interpret: bool = False) -> jnp.ndarray:

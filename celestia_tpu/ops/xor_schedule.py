@@ -241,7 +241,7 @@ def compile_col_block(k: int, sp: int, idx: int) -> XorSchedule:
 
 
 def schedule_stats(k: int) -> dict:
-    """Host-readable schedule metrics (stamped into bench_cache by
+    """Host-readable schedule metrics (stamped into bench results by
     bench.py --xor-schedule)."""
     s = compile_schedule(k)
     return {
@@ -492,10 +492,9 @@ def _xor_fused_kernel(x_ref, a_ref, b_ref, r_ref, o_ref, d_ref, *,
     )
     o_ref[...] = packed
     k, t = packed.shape
-    nc = t // SHARE_SIZE
-    d_ref[...] = rs_pallas._leaf_digest_math(
-        packed, rs_pallas._parity_prefix(k * nc)
-    )
+    rs_pallas._store_digests(d_ref, rs_pallas._leaf_digest_math(
+        packed, rs_pallas._parity_prefix(k * (t // SHARE_SIZE))
+    ))
 
 
 @functools.lru_cache(maxsize=8)
@@ -516,11 +515,11 @@ def _xor_fused_call(k: int, n: int, interpret: bool):
         + _sched_in_specs(sched, pl),
         out_specs=[
             pl.BlockSpec((k, tile), lambda i: (0, i)),
-            pl.BlockSpec((k, nct, 8), lambda i: (0, i, 0)),
+            rs_pallas._digest_spec(pl, k, nct),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, n), jnp.uint8),
-            jax.ShapeDtypeStruct((k, n // SHARE_SIZE, 8), jnp.uint32),
+            rs_pallas._digest_shape(k, n),
         ],
         interpret=interpret,
     )
@@ -540,10 +539,13 @@ def encode2d_xor_hash(x2: jnp.ndarray, interpret: bool = False):
     Same output contract as rs_pallas.encode2d_hash — the parity bytes
     feed the SHA stage without leaving VMEM; only the contraction
     spelling differs."""
+    from celestia_tpu.ops import rs_pallas
+
     k, n = x2.shape
-    return _xor_fused_call(k, n, interpret)(
+    parity, d = _xor_fused_call(k, n, interpret)(
         x2, *_sched_operands(compile_schedule(k))
     )
+    return parity, rs_pallas._digests_to_cells(d, k)
 
 
 def encode2d_xor_hash_reference(x2, tile=None):
@@ -563,13 +565,11 @@ def encode2d_xor_hash_reference(x2, tile=None):
     for i in range(grid):
         xt = x2[:, i * tile : (i + 1) * tile]
         p = _xor_encode_math(xt, sched)
-        parity.append(p)
-        digests.append(
-            rs_pallas._leaf_digest_math(
-                p, rs_pallas._parity_prefix(k * (tile // SHARE_SIZE))
-            )
-        )
+        parity.append(np.asarray(p))
+        digests.append(jnp.stack(rs_pallas._leaf_digest_math(
+            p, rs_pallas._parity_prefix(k * (tile // SHARE_SIZE))
+        )))
     return (
-        np.concatenate([np.asarray(p) for p in parity], axis=1),
-        np.concatenate([np.asarray(d) for d in digests], axis=1),
+        np.concatenate(parity, axis=1),
+        np.asarray(rs_pallas._digests_to_cells(jnp.stack(digests), k)),
     )

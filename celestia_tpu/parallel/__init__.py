@@ -66,11 +66,14 @@ def configure_mesh(mesh: Mesh | None) -> None:
 
 def sharded_extend_and_root(mesh: Mesh, k: int):
     """Compiled batched extend+root with (dp, sp) input sharding; XLA
-    inserts the collectives implied by the shardings."""
+    inserts the collectives implied by the shardings. XLA cannot
+    partition a Mosaic kernel, so this spelling pins the XLA extend
+    (fused=False) on every backend."""
     m2 = jnp.asarray(rs_tpu.encode_bit_matrix(k))
     in_sharding = NamedSharding(mesh, P("dp", "sp", None, None))
     return jax.jit(
-        lambda s: extend_and_root_batched(s, m2), in_shardings=in_sharding
+        lambda s: extend_and_root_batched(s, m2, fused=False),
+        in_shardings=in_sharding,
     )
 
 
@@ -79,21 +82,8 @@ def sharded_extend_and_root(mesh: Mesh, k: int):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map: the replication-check kwarg was renamed
-    check_rep -> check_vma across JAX releases; pass whichever exists."""
-    import inspect
-
-    try:
-        sm = jax.shard_map  # jax >= 0.6
-    except AttributeError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = False
-    elif "check_rep" in params:  # pragma: no cover
-        kw["check_rep"] = False
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _contraction_ops(k: int, sp: int, m2, xor: bool):

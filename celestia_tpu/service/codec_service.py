@@ -91,12 +91,9 @@ class CodecBackend:
 
     @staticmethod
     def _tpu_available() -> bool:
-        try:
-            import jax
+        from celestia_tpu.app.app import accelerator_available
 
-            return any(d.platform != "cpu" for d in jax.devices())
-        except Exception:  # noqa: BLE001 — no jax/device = host backends
-            return False
+        return accelerator_available()
 
     def _to_array(self, shares: bytes, width: int, share_size: int) -> np.ndarray:
         expect = width * width * share_size

@@ -51,7 +51,7 @@ log = logger("slo")
 DEFAULT_WINDOWS = ((300.0, 60.0, 14.4), (3600.0, 300.0, 6.0))
 
 # a crossover table (app/calibration.py) older than this is stale: the
-# tunnel/hardware it measured may no longer exist. measured_at == 0
+# hardware it measured may no longer exist. measured_at == 0
 # means "no timestamp recorded" (hand-built tables) and never expires.
 CROSSOVER_MAX_AGE_S = 7 * 24 * 3600.0
 

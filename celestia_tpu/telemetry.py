@@ -30,7 +30,7 @@ import time
 
 # Bucket bounds in seconds, ~1-2.5-5 per decade from 100 µs to 60 s
 # (ADR-013): sliced transfers sit in the 0.1-1 ms decade, single-square
-# device extends in 1-100 ms, repairs + tunnel-bound fetches in 0.1-10 s,
+# device extends in 1-100 ms, repairs + bulk fetches in 0.1-10 s,
 # and the 30/60 s tail catches pathological (fault-injected or degraded)
 # requests without folding them into +Inf.
 DEFAULT_BUCKETS = (

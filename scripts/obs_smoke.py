@@ -17,8 +17,7 @@ node/rpc.py handler — and fails (non-zero exit) unless:
      offending check named,
   5. unknown GET routes (including "/") return the consistent JSON 404
      body,
-  6. the perf-regression sentinel passes on the committed BENCH_r*.json
-     history and FAILS on a synthetic 2x regression fixture.
+  6. the perf-regression sentinel passes on the repo tree.
 
 CPU-only, seconds warm. The node runs the numpy extend backend so the
 gate needs no accelerator and no native build.
@@ -28,9 +27,7 @@ from __future__ import annotations
 
 import json
 import os
-import shutil
 import sys
-import tempfile
 import urllib.error
 import urllib.request
 
@@ -140,33 +137,11 @@ def check_node() -> None:
 def check_bench_gate() -> None:
     from celestia_tpu.tools import perf_ledger
 
+    # the repo carries no BENCH records since PR 21 (the driver's
+    # PERF_LEDGER.jsonl holds the chip record); regression detection
+    # itself is pinned by tests/test_perf_ledger.py
     result = perf_ledger.check(REPO)
-    gate(result["ok"], "bench gate passes on committed BENCH history")
-
-    # synthetic 2x regression: copy the history, append a round where
-    # every tracked wall doubled — the sentinel must catch it
-    with tempfile.TemporaryDirectory() as tmp:
-        import glob as glob_mod
-
-        for p in glob_mod.glob(os.path.join(REPO, "BENCH_r*.json")):
-            shutil.copy(p, tmp)
-        shutil.copy(os.path.join(REPO, "bench_cache.json"), tmp)
-        cache = json.load(open(os.path.join(tmp, "bench_cache.json")))
-        for cfg in cache.get("configs", {}).values():
-            for field, v in list(cfg.items()):
-                if isinstance(v, (int, float)) and field.endswith("_ms"):
-                    cfg[field] = v * 2.0
-        for rec in cache.get("headlines", {}).values():
-            if isinstance(rec, dict) and isinstance(rec.get("value"),
-                                                    (int, float)):
-                rec["value"] = rec["value"] * 2.0
-        with open(os.path.join(tmp, "bench_cache.json"), "w") as f:
-            json.dump(cache, f)
-        result = perf_ledger.check(tmp)
-        regressed = [m for m, r in result["metrics"].items()
-                     if r["regressed"]]
-        gate(not result["ok"] and regressed,
-             f"bench gate catches synthetic 2x regression ({regressed})")
+    gate(result["ok"], "bench gate passes on the repo tree")
 
 
 def main() -> int:

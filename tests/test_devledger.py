@@ -192,6 +192,33 @@ class TestWatchdog:
 # unified HBM ledger
 
 
+class TestCompileCacheCounters:
+    @pytest.mark.parametrize("entry,label", [("ops.fused", "ops.fused"),
+                                             (None, "other")])
+    def test_lookups_and_hits_attributed(self, entry, label):
+        """jax's cache events land on the in-flight entry, or on
+        `other` outside an instrumented builder."""
+        from jax import monitoring
+
+        devledger.install_monitoring()
+        devledger.install_monitoring()  # idempotent: one listener
+        misses = f'xla_compile_cache_miss_total{{entry="{label}"}}'
+        hits = f'xla_compile_cache_hit_total{{entry="{label}"}}'
+        before = (metrics.counters.get(misses, 0),
+                  metrics.counters.get(hits, 0))
+        devledger._compiling.entry = entry
+        try:
+            for _ in range(2):
+                monitoring.record_event("/jax/compilation_cache/cache_misses")
+            monitoring.record_event("/jax/compilation_cache/cache_hits")
+            monitoring.record_event(  # not a hit or a miss
+                "/jax/compilation_cache/compile_requests_use_cache")
+        finally:
+            devledger._compiling.entry = None
+        assert metrics.counters.get(misses, 0) - before[0] == 2
+        assert metrics.counters.get(hits, 0) - before[1] == 1
+
+
 class _Owner:
     def __init__(self, n):
         self.n = n

@@ -36,3 +36,19 @@ class TestNativeParity:
 
         items = [bytes([i]) * 90 for i in range(5)]
         assert native.merkle_root(items) == py_merkle(items)
+
+
+def test_build_key_changes_with_a_source_byte(tmp_path):
+    """The library name carries a hash of the committed sources: one
+    changed byte means a fresh build, never a stale .so."""
+    srcs = []
+    for src in native._SOURCES:
+        dst = tmp_path / src.name
+        dst.write_bytes(src.read_bytes())
+        srcs.append(dst)
+    key = native.build_key(srcs)
+    assert key == native.build_key(native._SOURCES)
+    data = bytearray(srcs[1].read_bytes())
+    data[len(data) // 2] ^= 1
+    srcs[1].write_bytes(bytes(data))
+    assert native.build_key(srcs) != key

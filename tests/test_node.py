@@ -339,6 +339,49 @@ class TestExtendBackend:
         monkeypatch.setattr(native, "available", lambda: False)
         assert app.resolve_extend_backend(128) == "numpy"
 
+    def test_accelerator_init_failure_is_logged(self, monkeypatch):
+        """A device that fails to initialize reads as "no accelerator",
+        but never silently: the probe logs the exception's type and
+        text at warning level."""
+        import logging
+
+        import jax
+
+        import celestia_tpu.app.app as app_mod
+
+        records = []
+
+        class Grab(logging.Handler):
+            def emit(self, record):
+                records.append(record)
+
+        def broken():
+            raise RuntimeError("TPU initialization failed: busy")
+
+        monkeypatch.setattr(app_mod, "_accel_probe", None)
+        monkeypatch.setattr(jax, "devices", broken)
+        handler = Grab()
+        logger = logging.getLogger("celestia_tpu.app")
+        logger.addHandler(handler)
+        try:
+            assert app_mod.accelerator_available() is False
+        finally:
+            logger.removeHandler(handler)
+        warn = [r for r in records if r.levelno == logging.WARNING]
+        assert warn and "accelerator init failed" in warn[0].getMessage()
+        assert "RuntimeError: TPU initialization failed: busy" in str(
+            warn[0].kv["error"])
+
+    @pytest.mark.parametrize("backend,device", [("numpy", False),
+                                                ("tpu", True)])
+    def test_boot_extend_backend(self, backend, device):
+        """`cli start` boots through Node.boot_extend_backend: only a
+        device backend turns on the blob arena and EDS retention."""
+        node = new_node(extend_backend=backend)
+        assert node.boot_extend_backend() == backend
+        assert (node.app.blob_pool is not None) is device
+        assert node.extend_blocks is device
+
     def test_cross_backend_proposal_acceptance(self):
         """A proposal produced on the device path must be accepted by a
         validator running numpy (and vice versa): process_proposal

@@ -406,7 +406,17 @@ class TestGaugeFamilyRoundTrip:
     NASTY = ('plain', 'quo"te', 'back\\slash', 'new\nline',
              'all\\three\n"at once')
 
-    def test_owner_labeled_family_round_trips_to_disk(self, tmp_path):
+    @pytest.fixture
+    def no_ledger_owners(self, monkeypatch):
+        """The scrape pull-publishes the process-wide device ledger into
+        the test's registry; owners an earlier test on this worker left
+        registered would join the family. Hide them for the test."""
+        from celestia_tpu import devledger
+
+        monkeypatch.setattr(devledger.ledger, "_owners", [])
+
+    def test_owner_labeled_family_round_trips_to_disk(self, tmp_path,
+                                                      no_ledger_owners):
         reg = Registry()
         s, path = _scraper(tmp_path, reg)
         for t in range(1, 5):
