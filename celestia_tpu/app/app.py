@@ -706,6 +706,12 @@ class App:
     # CheckTx (mempool admission). ref: app/check_tx.go:15-51
 
     def check_tx(self, raw_tx: bytes, recheck: bool = False) -> TxResult:
+        with tracing.span("app.check_tx", recheck=recheck) as sp:
+            res = self._check_tx_inner(raw_tx, recheck)
+            sp.set(code=res.code)
+            return res
+
+    def _check_tx_inner(self, raw_tx: bytes, recheck: bool) -> TxResult:
         btx, is_blob = blob_pkg.unmarshal_blob_tx(raw_tx)
         mode = ExecMode.RECHECK if recheck else ExecMode.CHECK
         try:
@@ -931,6 +937,12 @@ class App:
 
     def deliver_tx(self, raw_tx: bytes) -> TxResult:
         """ref: app/deliver_tx.go:10-23"""
+        with tracing.span("app.deliver_tx") as sp:
+            res = self._deliver_tx_inner(raw_tx)
+            sp.set(code=res.code)
+            return res
+
+    def _deliver_tx_inner(self, raw_tx: bytes) -> TxResult:
         btx, is_blob = blob_pkg.unmarshal_blob_tx(raw_tx)
         inner = btx.tx if is_blob else raw_tx
         try:
@@ -1282,16 +1294,17 @@ class App:
         return t
 
     def commit(self) -> bytes:
-        if self._deliver_store is not None:
-            self._deliver_store.write()
-            self._deliver_store = None
-            self._deliver_ctx = None
-        if self.upgrade.should_upgrade():
-            self.app_version = self.upgrade.pending_app_version
-            self.upgrade.mark_upgrade_complete()
-        self.height += 1
-        self._check_store = None  # re-branch check state from committed state
-        return self.store.commit()
+        with tracing.span("app.commit"):
+            if self._deliver_store is not None:
+                self._deliver_store.write()
+                self._deliver_store = None
+                self._deliver_ctx = None
+            if self.upgrade.should_upgrade():
+                self.app_version = self.upgrade.pending_app_version
+                self.upgrade.mark_upgrade_complete()
+            self.height += 1
+            self._check_store = None  # re-branch check state from committed state
+            return self.store.commit()
 
     # ------------------------------------------------------------------ #
     # ExtendBlock (post-consensus EDS recompute). ref: app/extend_block.go:14

@@ -25,7 +25,8 @@ three planes in one leaf-locked object:
    annotation, and a `RetraceError` under strict mode (tests, smokes,
    `CELESTIA_STRICT_RETRACE=1`). An lru-evicted key that gets rebuilt
    is a compile but NOT a retrace — the per-entry seen-key set outlives
-   the lru cache, mirroring jax's own process-level trace cache.
+   the lru cache, mirroring jax's own process-level trace cache — and
+   is counted apart as `xla_rebuild_total{entry}`.
 
 2. **Unified device-byte ledger.** Every HBM-holding subsystem
    (PagedEdsCache, ResidentEdsCache, DeviceBlobArena, BlockPipeline
@@ -37,10 +38,12 @@ three planes in one leaf-locked object:
    side leak detector the RSS gauge can't be, drift-judged by the soak
    scenario (`no_monotone_drift`).
 
-3. **Device-utilization timeline.** The dispatcher owns the device
+3. **Dispatch-utilization timeline.** The dispatcher owns the device
    stream (ADR-016), so its per-job exec durations fold into a windowed
-   `device_busy_ratio` gauge that rides `.ctts` recordings, the
-   obs_report dashboard, and the `/debug/device` RPC route.
+   `dispatch_busy_ratio` gauge that rides `.ctts` recordings, the
+   obs_report dashboard, and the `/debug/device` RPC route. It is host
+   wall time inside the dispatcher's exec calls, not device time: the
+   device's busy time comes from the profiler trace.
 
 Lock discipline (specs/serving.md §Lock ordering): ``devledger._lock``
 is a LEAF — it is never held across an owner callback, a metric write,
@@ -193,7 +196,11 @@ class DeviceLedger:
     def note_build(self, entry: str, key: str) -> bool:
         """Record one builder invocation for (entry, key); returns (and
         under strict mode raises on) whether it was a retrace: the
-        entry was known before warmup ended and the key is new."""
+        entry was known before warmup ended and the key is new. A key
+        the entry has already built is an lru eviction rebuilt, counted
+        as `xla_rebuild_total{entry}` (wasted work, never a retrace);
+        the series starts at 0 with the entry's first build, so a
+        reader can tell "no rebuilds" from "not counted"."""
         with self._lock:
             seen = self._seen.setdefault(entry, set())
             known = len(seen) > 0
@@ -204,6 +211,12 @@ class DeviceLedger:
             if retrace:
                 self._retraces.append(
                     {"entry": entry, "key": key, "t": time.time()})
+        if not known or not fresh:
+            try:
+                telemetry.metrics.incr_counter(
+                    "xla_rebuild_total", 0.0 if fresh else 1.0, entry=entry)
+            except Exception:  # noqa: BLE001 — telemetry never breaks builds
+                pass
         if retrace:
             try:
                 telemetry.metrics.incr_counter(
@@ -397,9 +410,10 @@ class DeviceLedger:
             self._trim_busy_locked(end)
 
     def busy_ratio(self, now: float | None = None) -> float:
-        """Fraction of the trailing window the device lane spent
-        executing, clamped to 1.0 (several dispatchers in one process
-        can oversubscribe the wall clock)."""
+        """Fraction of the trailing window the dispatcher spent in its
+        exec calls, host wall time, clamped to 1.0 (several dispatchers
+        in one process can oversubscribe the wall clock). Device busy
+        time comes from the profiler trace, not from here."""
         now = time.monotonic() if now is None else now
         with self._lock:
             self._trim_busy_locked(now)
@@ -420,7 +434,7 @@ class DeviceLedger:
         """Export the gauge plane into `registry` (the process registry
         by default): `device_ledger_bytes{owner}`,
         `device_ledger_unattributed_bytes`, `device_ledger_live_bytes`,
-        `device_busy_ratio`. Called from the /metrics route and the
+        `dispatch_busy_ratio`. Called from the /metrics route and the
         tsdb scrapers — pull-driven, so nobody scraping costs zero
         cycles. Returns the snapshot it published."""
         reg = registry if registry is not None else telemetry.metrics
@@ -433,7 +447,7 @@ class DeviceLedger:
                           float(snap["unattributed_bytes"]))
             reg.set_gauge("device_ledger_live_bytes",
                           float(snap["live_bytes"]))
-            reg.set_gauge("device_busy_ratio", self.busy_ratio())
+            reg.set_gauge("dispatch_busy_ratio", self.busy_ratio())
         except Exception:  # noqa: BLE001
             pass
         return snap

@@ -323,6 +323,12 @@ class Node:
     # --- mempool admission ---
 
     def broadcast_tx(self, raw: bytes) -> TxResult:
+        with tracing.span("node.broadcast_tx") as sp:
+            res = self._broadcast_tx_traced(raw, sp)
+            sp.set(code=res.code, bytes=len(raw))
+            return res
+
+    def _broadcast_tx_traced(self, raw: bytes, sp) -> TxResult:
         with self._lock:
             res = self.app.check_tx(raw)
             if res.code == 0:
@@ -336,6 +342,7 @@ class Node:
 
             btx, is_blob = blob_pkg.unmarshal_blob_tx(raw)
             if is_blob:
+                sp.set(blobs=len(btx.blobs))
                 try:
                     # put_many dispatches every blob's upload before the
                     # arena inserts — the DMAs overlap instead of

@@ -26,7 +26,7 @@ import functools
 import hashlib
 import threading
 
-from celestia_tpu import devledger
+from celestia_tpu import devledger, tracing
 
 
 def blob_key(data: bytes) -> bytes:
@@ -198,14 +198,28 @@ class DeviceBlobArena:
         blob i+1's bytes stream over the interconnect while blob i's
         insert runs, instead of the strict upload→insert lockstep of
         sequential put() calls. Allocator/flip/dedup semantics are
-        identical to put(); returns the content keys in input order."""
+        identical to put(); returns the content keys in input order.
+
+        The call is one ``blob_pool.stage`` span (attrs ``blobs``,
+        ``staged``, ``resident_hits``)."""
+        with tracing.span("blob_pool.stage") as sp:
+            keys, inserted, hits = self._put_many(datas)
+            sp.set(blobs=len(datas), staged=inserted, resident_hits=hits)
+            return keys
+
+    def _put_many(self, datas: list[bytes]) -> tuple[list[bytes], int, int]:
+        """put_many's body; also returns how many blobs it inserted and
+        how many were already resident."""
         with self._lock:
             plan: list[tuple[bytes, bytes, bool]] = []
             seen: set[bytes] = set()
+            hits = 0
             for data in datas:
                 key = blob_key(data)
+                resident = key in self._offsets
+                hits += resident
                 stage = not (
-                    key in self._offsets
+                    resident
                     or key in seen
                     or _pad_len(len(data)) > self._half
                 )  # False: resident/oversized/dup-in-batch
@@ -220,15 +234,17 @@ class DeviceBlobArena:
         ]
         with self._lock:
             keys = []
+            inserted = 0
             for key, data, dev in staged:
                 if dev is not None and key not in self._offsets:
                     pad = _pad_len(len(data))
                     offset = self._alloc_locked(pad)
                     self._arena = _jitted_insert(pad)(self._arena, dev, offset)
                     self._offsets[key] = (offset, len(data))
+                    inserted += 1
                 keys.append(key)
             self._publish_metrics()
-            return keys
+            return keys, inserted, hits
 
     def _publish_metrics(self) -> None:
         """Operator visibility on /metrics: how much of the mempool's

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import functools
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -336,16 +335,23 @@ def extend_and_roots_only(shares: jnp.ndarray, m2: jnp.ndarray):
     return _roots_of(shares, m2)
 
 
+def _named_jit(entry: str, run):
+    """jax.jit with the program named after its devledger entry: XLA
+    calls the module ``jit_run.<entry>``, so a profiler trace tells the
+    programs apart while ``^jit_run\\b`` still matches every one."""
+    run.__name__ = run.__qualname__ = f"run.{entry}"
+    return jax.jit(run)
+
+
 @functools.lru_cache(maxsize=8)
 @devledger.instrument_builder("extend.for_k")
 def _jitted_for_k(k: int):
     m2 = jnp.asarray(rs_tpu.encode_bit_matrix(k))
 
-    @jax.jit
     def run(shares):
         return extend_and_root(shares, m2)
 
-    return run
+    return _named_jit("extend.for_k", run)
 
 
 @functools.lru_cache(maxsize=8)
@@ -353,30 +359,10 @@ def _jitted_for_k(k: int):
 def _jitted_roots_for_k(k: int):
     m2 = jnp.asarray(rs_tpu.encode_bit_matrix(k))
 
-    @jax.jit
     def run(shares):
         return extend_and_roots_only(shares, m2)
 
-    return run
-
-
-def _profile_fence(out, entry: str, dispatch_start: float,
-                   **attrs) -> None:
-    """Fenced device-time profiling (ADR-022, opt-in): when this
-    dispatch is profile-sampled, block until the result is ready and
-    emit a ``profile.fence`` span covering dispatch→ready — the REAL
-    device completion time the async dispatch queue hides from wall
-    spans. Off by default (``tracing.enable_profiling``): a fence
-    serializes the device stream, which costs exactly the
-    dispatch/fetch overlap the resident paths exist to keep."""
-    if not tracing.profile_sample():
-        return
-    try:
-        jax.block_until_ready(out)
-        tracing.emit("profile.fence", dispatch_start, entry=entry,
-                     fenced=True, **attrs)
-    except Exception:  # noqa: BLE001
-        pass
+    return _named_jit("extend.roots_for_k", run)
 
 
 # ------------------------------------------------------------------ #
@@ -534,12 +520,10 @@ def extend_roots_device(shares: np.ndarray):
         # covers dispatch through the host fetch of all three outputs
         with tracing.span("extend.rs_nmt", backend="tpu", k=k,
                           fused="rs+nmt", sharded=mesh is not None):
-            t0 = time.perf_counter()
             if mesh is not None:
                 eds, rows, cols, _dah = _jitted_rowsharded(k)(dev)
             else:
                 eds, rows, cols = _jitted_roots_for_k(k)(dev)
-            _profile_fence(cols, "extend_roots_device", t0, k=k)
         # SDC model: the result tensor is damaged in flight (HBM upset,
         # bad D2H) — the audit below must catch what the flip injects
         flip = faults.fire("device.extend.output",
@@ -573,12 +557,10 @@ def extend_roots_device_resident(shares: np.ndarray):
                    else jnp.asarray(shares))
         with tracing.span("extend.rs_nmt", backend="tpu", k=k,
                           fused="rs+nmt", sharded=mesh is not None):
-            t0 = time.perf_counter()
             if mesh is not None:
                 eds, rows, cols, _dah = _jitted_rowsharded(k)(dev)
             else:
                 eds, rows, cols = _jitted_roots_for_k(k)(dev)
-            _profile_fence(cols, "extend_roots_device_resident", t0, k=k)
         flip = faults.fire("device.extend.output",
                            entry="extend_roots_device_resident")
         if flip is not None:
@@ -594,12 +576,11 @@ def extend_roots_device_resident(shares: np.ndarray):
 @functools.lru_cache(maxsize=8)
 @devledger.instrument_builder("extend.eds_roots")
 def _jitted_eds_roots(k: int):
-    @jax.jit
     def run(eds):
         leaf_ns = _leaf_namespaces(eds[:k, :k, :NAMESPACE_SIZE], k)
         return nmt_roots_of_eds(eds, leaf_ns)
 
-    return run
+    return _named_jit("extend.eds_roots", run)
 
 
 def eds_roots_device(eds):
@@ -610,22 +591,19 @@ def eds_roots_device(eds):
     k = int(eds.shape[0]) // 2
     with tracing.span("extend.nmt", backend="tpu", k=k,
                       entry="eds_roots_device"):
-        t0 = time.perf_counter()
         rows, cols = _jitted_eds_roots(k)(jnp.asarray(eds))
-        _profile_fence(cols, "eds_roots_device", t0, k=k)
         return np.asarray(rows), np.asarray(cols)
 
 
 @functools.lru_cache(maxsize=8)
 @devledger.instrument_builder("extend.row_levels")
 def _jitted_row_levels(k: int):
-    @jax.jit
     def run(eds):
         leaf_ns = _leaf_namespaces(eds[:k, :k, :NAMESPACE_SIZE], k)
         leaf_nodes = nmt_leaf_nodes(leaf_ns, eds)  # (2k, 2k, 90)
         return nmt_reduce_levels(leaf_nodes)
 
-    return run
+    return _named_jit("extend.row_levels", run)
 
 
 def eds_row_levels_device(eds) -> list[np.ndarray]:
@@ -643,13 +621,11 @@ def eds_row_levels_device(eds) -> list[np.ndarray]:
     with tracing.span("extend.nmt_levels", backend="tpu", k=k,
                       entry="eds_row_levels_device",
                       sharded=mesh is not None):
-        t0 = time.perf_counter()
         if mesh is not None:
             dev = _stage_sharded(eds, mesh)
             levels = _jitted_rowsharded_levels(k)(dev)
         else:
             levels = _jitted_row_levels(k)(jnp.asarray(eds))
-        _profile_fence(levels[-1], "eds_row_levels_device", t0, k=k)
         return [np.asarray(lv) for lv in levels]
 
 
@@ -818,13 +794,12 @@ def _jitted_assembled_roots(k: int, h_pad: int, b_pad: int, hc_pad: int,
                             n_arena: int):
     m2 = jnp.asarray(rs_tpu.encode_bit_matrix(k))
 
-    @jax.jit
     def run(arena, host_shares, blob_meta, host_sparse, ns_len_table):
         square = _assemble_square(arena, host_shares, blob_meta,
                                   host_sparse, ns_len_table, k)
         return _rows_cols_only(square, m2)
 
-    return run
+    return _named_jit("extend.assembled_roots", run)
 
 
 def _pow2_at_least(n: int, floor: int) -> int:
@@ -1034,12 +1009,10 @@ def roots_device(shares: np.ndarray):
                    else jnp.asarray(shares))
         with tracing.span("extend.rs_nmt", backend="tpu", k=k,
                           fused="rs+nmt", sharded=mesh is not None):
-            t0 = time.perf_counter()
             if mesh is not None:
                 rows, cols, _dah = _jitted_rowsharded_roots(k)(dev)
             else:
                 rows, cols = _jitted_roots_noeds(k)(dev)
-            _profile_fence(cols, "roots_device", t0, k=k)
             return np.asarray(rows), np.asarray(cols)
 
 
@@ -1065,9 +1038,7 @@ def batched_roots_device(shares):
         chunk = _batch_chunk(k, b)
         if chunk >= b:
             stacked = shares if isinstance(shares, np.ndarray) else np.stack(shares)
-            t0 = time.perf_counter()
             rows, cols = _jitted_batched_roots(k)(jnp.asarray(stacked))
-            _profile_fence(cols, "batched_roots_device", t0, k=k, batch=b)
             return np.asarray(rows), np.asarray(cols)
         if chunk > 1:
             fn = _jitted_chunk_roots(k, chunk)
@@ -1108,11 +1079,9 @@ def extend_and_root_device(shares: np.ndarray):
                    else jnp.asarray(shares))
         with tracing.span("extend.rs_nmt", backend="tpu", k=k,
                           fused="rs+nmt+dah", sharded=mesh is not None):
-            t0 = time.perf_counter()
             if mesh is not None:
                 eds, rows, cols, dah = _jitted_rowsharded(k)(dev)
             else:
                 eds, rows, cols, dah = _jitted_for_k(k)(dev)
-            _profile_fence(dah, "extend_and_root_device", t0, k=k)
             return (np.asarray(eds), np.asarray(rows), np.asarray(cols),
                     np.asarray(dah))

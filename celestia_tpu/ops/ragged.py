@@ -118,8 +118,6 @@ def _gather_rows_direct(descs, site: str) -> list:
         pi = jnp.asarray(transfers._pad_pow2(page_idx), dtype=jnp.int32)
         ri = jnp.asarray(transfers._pad_pow2(row_idx), dtype=jnp.int32)
         out_dev = gather(stacked, pi, ri)
-        transfers._profile_fence(out_dev, site, start,
-                                 n=len(members), pages=len(pages))
         # device-side slice to the true member count BEFORE D2H: the
         # pow2 pad is cut on device and never fetched, so the
         # transfer_bytes increment equals the per-call sum

@@ -308,30 +308,9 @@ def _eds_rows_batch_direct(dev, indices, site: str) -> np.ndarray:
     rows_fn, _ = _jitted_batch_slicers()
     padded = jnp.asarray(_pad_pow2(idx), dtype=jnp.int32)
     out_dev = rows_fn(dev, padded)
-    _profile_fence(out_dev, site, start, n=len(idx))
     out = np.asarray(out_dev[: len(idx)])
     _record(site, "d2h", out.nbytes, start)
     return out
-
-
-def _profile_fence(out_dev, entry: str, dispatch_start: float,
-                   **attrs) -> None:
-    """Fenced device-time profiling (ADR-022, opt-in): when this
-    dispatch is profile-sampled, block until the result is ready and
-    emit a ``profile.fence`` span covering dispatch→ready — the REAL
-    device completion time async dispatch hides. Off by default
-    (``tracing.enable_profiling``): a fence serializes the device
-    stream, which would cost exactly the overlap ADR-019 measured."""
-    if not tracing.profile_sample():
-        return
-    try:
-        import jax
-
-        jax.block_until_ready(out_dev)
-        tracing.emit("profile.fence", dispatch_start, entry=entry,
-                     fenced=True, **attrs)
-    except Exception:  # noqa: BLE001 — profiling must never break serving
-        pass
 
 
 def eds_cells_batch(dev, coords, *, site: str = "eds.cells_batch") -> np.ndarray:
@@ -357,7 +336,6 @@ def _eds_cells_batch_direct(dev, coords, site: str) -> np.ndarray:
     rr = jnp.asarray([p[0] for p in padded], dtype=jnp.int32)
     cc = jnp.asarray([p[1] for p in padded], dtype=jnp.int32)
     out_dev = cells_fn(dev, rr, cc)
-    _profile_fence(out_dev, site, start, n=len(pts))
     out = np.asarray(out_dev[: len(pts)])
     _record(site, "d2h", out.nbytes, start)
     return out

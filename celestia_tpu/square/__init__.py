@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from celestia_tpu import appconsts, inclusion
+from celestia_tpu import appconsts, inclusion, tracing
 from celestia_tpu import blob as blob_pkg
 from celestia_tpu import namespace as ns_pkg
 from celestia_tpu.shares import (
@@ -389,22 +389,26 @@ def build_ex(
 ) -> tuple[Square, list[bytes], Builder]:
     """build() that also returns the Builder (blob-placement provenance
     for the device-side square assembly)."""
-    builder = Builder(max_square_size, app_version)
-    normal_txs: list[bytes] = []
-    blob_txs: list[bytes] = []
-    for tx in txs:
-        blob_tx, is_blob_tx = blob_pkg.unmarshal_blob_tx(tx)
-        if is_blob_tx:
-            try:
-                appended = builder.append_blob_tx(blob_tx)
-            except ValueError:
-                continue  # invalid blob tx (e.g. double-wrapped inner): drop
-            if appended:
-                blob_txs.append(tx)
-        else:
-            if builder.append_tx(tx):
-                normal_txs.append(tx)
-    return builder.export(), normal_txs + blob_txs, builder
+    with tracing.span("square.build") as sp:
+        builder = Builder(max_square_size, app_version)
+        normal_txs: list[bytes] = []
+        blob_txs: list[bytes] = []
+        for tx in txs:
+            blob_tx, is_blob_tx = blob_pkg.unmarshal_blob_tx(tx)
+            if is_blob_tx:
+                try:
+                    appended = builder.append_blob_tx(blob_tx)
+                except ValueError:
+                    continue  # invalid blob tx (e.g. double-wrapped inner): drop
+                if appended:
+                    blob_txs.append(tx)
+            else:
+                if builder.append_tx(tx):
+                    normal_txs.append(tx)
+        square = builder.export()
+        if tracing.enabled():
+            sp.set(txs=len(txs), k=square_size(len(square)))
+        return square, normal_txs + blob_txs, builder
 
 
 def build(txs: list[bytes], app_version: int, max_square_size: int) -> tuple[Square, list[bytes]]:
@@ -417,8 +421,12 @@ def construct_ex(
     txs: list[bytes], app_version: int, max_square_size: int
 ) -> tuple[Square, Builder]:
     """construct() that also returns the Builder (provenance)."""
-    b = Builder.from_txs(max_square_size, app_version, txs)
-    return b.export(), b
+    with tracing.span("square.construct") as sp:
+        b = Builder.from_txs(max_square_size, app_version, txs)
+        square = b.export()
+        if tracing.enabled():
+            sp.set(txs=len(txs), k=square_size(len(square)))
+        return square, b
 
 
 def construct(txs: list[bytes], app_version: int, max_square_size: int) -> Square:

@@ -38,7 +38,7 @@ _TICKS = "▁▂▃▄▅▆▇█"
 DEFAULT_SELECT = (
     "process_rss_bytes", "process_open_fds", "process_threads",
     "store_bytes", "store_heights", "store_read_only", "eds_cache_*",
-    "device_ledger_*", "device_busy_ratio", "xla_compile_total*",
+    "device_ledger_*", "dispatch_busy_ratio", "xla_compile_total*",
     "xla_retrace_total*",
 )
 

@@ -668,7 +668,7 @@ class RegistryScraper(Scraper):
         refresh_process_gauges(self._registry)
         # the device runtime ledger is pull-driven like the process
         # gauges: each scrape runs one owner audit, so recordings carry
-        # device_ledger_* / device_busy_ratio series for the drift judge
+        # device_ledger_* / dispatch_busy_ratio series for the drift judge
         devledger.publish(self._registry)
         return self._registry.prometheus_text()
 

@@ -379,7 +379,6 @@ def enabled() -> bool:
 def reset() -> None:
     """Test helper: drop all sinks and disable."""
     _tracer.reset()
-    disable_profiling()
     sinks = getattr(_stage_local, "sinks", None)
     if sinks:
         sinks.clear()
@@ -542,50 +541,6 @@ def merge_stages(stages: dict | None) -> None:
     if sink is not None:
         for name, seconds in stages.items():
             sink.add(name, seconds)
-
-
-# ---------------------------------------------------------------------- #
-# fenced device-time profiling (ADR-022)
-#
-# Async XLA dispatch returns before the device finishes, so wall spans
-# around jitted calls measure DISPATCH wall — honest for throughput,
-# a lie for device time. Profile mode brackets a 1-in-N sample of the
-# jitted extend/fused-hash/batched-read calls with block_until_ready()
-# fences and emits ``profile.fence`` spans carrying the fenced time.
-# OFF BY DEFAULT and opt-in only: a fence serializes the device stream,
-# which is exactly the overlap ADR-019's numbers depend on.
-
-_prof_lock = threading.Lock()
-_prof_every = 0          # 0 = profiling disabled
-_prof_counter = 0
-
-
-def enable_profiling(sample_every: int = 16) -> None:
-    """Fence 1 in ``sample_every`` jitted dispatches (opt-in)."""
-    global _prof_every, _prof_counter
-    with _prof_lock:
-        _prof_every = max(1, int(sample_every))
-        _prof_counter = 0
-
-
-def disable_profiling() -> None:
-    global _prof_every
-    with _prof_lock:
-        _prof_every = 0
-
-
-def profiling_enabled() -> bool:
-    return _prof_every > 0
-
-
-def profile_sample() -> bool:
-    """True when THIS dispatch should be fenced (counter-sampled)."""
-    if _prof_every == 0:
-        return False
-    global _prof_counter
-    with _prof_lock:
-        _prof_counter += 1
-        return _prof_counter % _prof_every == 0
 
 
 # ---------------------------------------------------------------------- #

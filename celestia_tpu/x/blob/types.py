@@ -12,6 +12,7 @@ from celestia_tpu import appconsts
 from celestia_tpu import blob as blob_pkg
 from celestia_tpu import inclusion
 from celestia_tpu import namespace as ns_pkg
+from celestia_tpu import tracing
 from celestia_tpu.blob import _field_bytes, _field_uint, _parse_fields, _require_wt
 from celestia_tpu.bech32 import bech32_decode
 from celestia_tpu.shares.splitters import sparse_shares_needed
@@ -190,6 +191,14 @@ def validate_blob_tx(btx: blob_pkg.BlobTx, sdk_tx=None):
     Accepts (and returns) the decoded inner Tx so hot-path callers that
     already decoded it don't pay a second protobuf parse.
     ref: x/blob/types/blob_tx.go:36-103"""
+    with tracing.span("blob.validate") as sp:
+        if tracing.enabled():
+            sp.set(blobs=len(btx.blobs),
+                   bytes=sum(len(b.data) for b in btx.blobs))
+        return _validate_blob_tx(btx, sdk_tx)
+
+
+def _validate_blob_tx(btx: blob_pkg.BlobTx, sdk_tx):
     from celestia_tpu.tx import Tx
 
     if sdk_tx is None:

@@ -19,7 +19,7 @@ live-array accounting). Fails (non-zero exit) unless:
      clamps oversubscription at 1.0;
   5. the `/debug/device` RPC route serves the watchdog + ledger +
      provenance document over the real node/rpc.py handler, and
-     `publish()` lands every `device_ledger_*` / `device_busy_ratio` /
+     `publish()` lands every `device_ledger_*` / `dispatch_busy_ratio` /
      `xla_*` gauge family in prometheus exposition text.
 """
 
@@ -160,7 +160,7 @@ def main() -> int:
                 timeout=10) as resp:
             text = resp.read().decode()
         for family in ("device_ledger_unattributed_bytes",
-                       "device_ledger_live_bytes", "device_busy_ratio"):
+                       "device_ledger_live_bytes", "dispatch_busy_ratio"):
             gate(f"\n{family}" in text or text.startswith(family),
                  f"/metrics exports {family}")
     finally:

@@ -90,21 +90,38 @@ class TestWatchdog:
         assert built == [2]
 
     def test_lru_evicted_key_rebuilt_is_a_compile_not_a_retrace(self):
+        """A rebuilt key is counted as xla_rebuild_total, never as a
+        retrace, strict mode included."""
         led = devledger.DeviceLedger()
         built = []
+        entry = "t.evict"
 
         @functools.lru_cache(maxsize=1)
-        @led.instrument_builder("t.evict")
+        @led.instrument_builder(entry)
         def build(k):
             built.append(k)
             return lambda: k
 
+        rebuilds = lambda: metrics.get_counter(  # noqa: E731
+            "xla_rebuild_total", entry=entry)
+        retraces = lambda: metrics.get_counter(  # noqa: E731
+            "xla_retrace_total", entry=entry)
+        r0, t0 = rebuilds(), retraces()
         build(1)
+        assert f'xla_rebuild_total{{entry="{entry}"}}' in metrics.counters
         build(2)  # evicts key 1 from the lru
+        assert rebuilds() == r0  # two fresh keys: no rebuild
         led.end_warmup()
         build(1)  # lru miss -> builder reruns, but the KEY is known
         assert built == [1, 2, 1]
         assert led.retrace_count() == 0
+        assert rebuilds() == r0 + 1
+        with led.strict_retraces():
+            build(2)  # evicted again by 1: a rebuild, and strict is quiet
+        assert built == [1, 2, 1, 2]
+        assert led.retrace_count() == 0
+        assert rebuilds() == r0 + 2
+        assert retraces() == t0
 
     def test_key_extra_makes_ambient_state_part_of_the_key(self):
         """A mesh flip the args don't carry must read as a distinct
@@ -345,7 +362,7 @@ class TestExportSurfaces:
                 == float(snap["unattributed_bytes"]))
         assert (reg.get_gauge("device_ledger_live_bytes")
                 == float(snap["live_bytes"]))
-        assert reg.get_gauge("device_busy_ratio") is not None
+        assert reg.get_gauge("dispatch_busy_ratio") is not None
 
     def test_debug_doc_shape_and_retrace_ring(self):
         led = devledger.DeviceLedger()

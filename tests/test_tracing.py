@@ -449,11 +449,10 @@ class TestTraceMerge:
 
 class TestDisabledPathAllocation:
     def test_disabled_hot_path_allocates_nothing(self):
-        """With tracing off, the whole ADR-022 surface — spans, stages,
-        profiling samples — must not allocate inside tracing.py (the
+        """With tracing off, the whole ADR-022 surface — spans and
+        stages — must not allocate inside tracing.py (the
         <2% storm-bench bar depends on it)."""
         assert not tracing.enabled()
-        assert not tracing.profiling_enabled()
 
         def hot():
             for _ in range(50):
@@ -465,7 +464,6 @@ class TestDisabledPathAllocation:
                     pass
                 tracing.add_stage("d2h", 0.001)
                 tracing.merge_stages({"prove": 0.1})
-                assert not tracing.profile_sample()
 
         hot()  # warm lazy state (thread-local attrs, code objects)
         filt = [tracemalloc.Filter(True, tracing.__file__)]

@@ -423,7 +423,7 @@ class TestGaugeFamilyRoundTrip:
             for i, owner in enumerate(self.NASTY):
                 reg.set_gauge("device_ledger_bytes",
                               t * 1000.0 + i, owner=owner)
-            reg.set_gauge("device_busy_ratio", 0.25 * t)
+            reg.set_gauge("dispatch_busy_ratio", 0.25 * t)
             s.scrape_once(t=float(t))
         s.stop(final_scrape=False)
         rec = tsdb.read(path)
@@ -445,8 +445,8 @@ class TestGaugeFamilyRoundTrip:
         assert owners == set(self.NASTY)
         # the scrape path pull-publishes the live ledger over this
         # gauge, so assert the series (not the injected value)
-        assert len(rec.series("device_busy_ratio")) == 4
-        assert rec.types["device_busy_ratio"] == "gauge"
+        assert len(rec.series("dispatch_busy_ratio")) == 4
+        assert rec.types["dispatch_busy_ratio"] == "gauge"
 
     def test_renderer_parser_dual_fuzz_on_label_values(self):
         """Seeded fuzz: random label values drawn from the escape
@@ -486,7 +486,7 @@ class TestObsReportDeviceSeries:
             reg.set_gauge("device_ledger_bytes", 4096.0 * t,
                           owner="eds_cache_paged")
             reg.set_gauge("device_ledger_unattributed_bytes", 512.0)
-            reg.set_gauge("device_busy_ratio", 0.5)
+            reg.set_gauge("dispatch_busy_ratio", 0.5)
             reg.incr_counter("xla_compile_total", 1.0, entry="extend.roots")
             reg.incr_counter("xla_retrace_total", 1.0, entry="extend.roots")
             s.scrape_once(t=float(t))
@@ -497,7 +497,7 @@ class TestObsReportDeviceSeries:
         names = [r["series"] for r in report["rows"]]
         assert 'device_ledger_bytes{owner="eds_cache_paged"}' in names
         assert "device_ledger_unattributed_bytes" in names
-        assert "device_busy_ratio" in names
+        assert "dispatch_busy_ratio" in names
         assert 'xla_compile_total{entry="extend.roots"}' in names
         assert 'xla_retrace_total{entry="extend.roots"}' in names
         text = obs_report.render_text(report)
