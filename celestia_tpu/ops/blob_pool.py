@@ -8,7 +8,12 @@ known long before the proposal: they arrive with the BlobTx at CheckTx.
 
 This module stages them: on mempool admission the node appends each
 blob's data into a fixed device arena (async `device_put` + a donated
-`dynamic_update_slice` — off the consensus hot path). At proposal time
+update — off the consensus hot path). A slot is the blob rounded up to
+4 KB; the bytes travel zero-padded to the slot's power-of-two size
+class, and the insert program is keyed by that class, so a 64 MB arena
+needs at most 14 programs, built once each, for any mix of blob sizes
+(one per 4 KB slot size would be thousands, rebuilt as sizes change). The
+program writes exactly the slot's bytes. At proposal time
 the device assembles the square itself (ops/extend_tpu.assembled_roots):
 only the compact tx/PFB/padding shares, the 34-byte share prefixes, and
 int32 offset vectors cross the interconnect — tens of KB instead of MB —
@@ -36,23 +41,55 @@ def blob_key(data: bytes) -> bytes:
 
 
 def _pad_len(n: int) -> int:
-    """Arena slots are rounded to 4 KB so the donated update-slice jit
-    compiles for a handful of sizes, not one per blob length."""
+    """Arena slot size: the blob rounded up to 4 KB. It decides offsets
+    and residency; the insert program is keyed by the slot's
+    `_size_class`, not by this."""
     return max(4096, (n + 4095) // 4096 * 4096)
 
 
-@functools.lru_cache(maxsize=16)
-@devledger.instrument_builder("blob_pool.insert")
-def _jitted_insert(pad: int):
+def _size_class(pad: int) -> int:
+    """The power of two a slot of `pad` bytes is staged at, and the key
+    of its insert program: at most log2(half / 4 KB) + 1 programs per
+    arena (14 at 64 MB), whatever mix of blob sizes arrives."""
+    return 1 << (pad - 1).bit_length()
+
+
+def _write_slot(arena, chunk, offset, pad):
+    """Write exactly chunk[:pad] at arena[offset:], leaving every other
+    byte (the next slot, the arena's end) as it was. `chunk` is one size
+    class long; `pad`, a multiple of 4 KB, is at most that. One
+    static-size piece per power of two, taken where that bit of `pad` is
+    set. An untaken piece writes back the arena's own bytes, which is a
+    no-op even where dynamic_slice and dynamic_update_slice clamp."""
     import jax
     import jax.numpy as jnp
 
-    def insert(arena, chunk, offset):
-        return jax.lax.dynamic_update_slice(arena, chunk, (offset,))
+    done = jnp.int32(0)
+    size = chunk.shape[0]
+    while size >= 4096:
+        take = (pad & size) != 0
+        at = offset + done
+        piece = jnp.where(
+            take,
+            jax.lax.dynamic_slice(chunk, (done,), (size,)),
+            jax.lax.dynamic_slice(arena, (at,), (size,)),
+        )
+        arena = jax.lax.dynamic_update_slice(arena, piece, (at,))
+        done = done + jnp.where(take, size, 0)
+        size //= 2
+    return arena
+
+
+@functools.lru_cache(maxsize=None)  # keys are size classes: bounded
+@devledger.instrument_builder("blob_pool.insert")
+def _jitted_insert(size_class: int):
+    """The insert for chunks `size_class` bytes long: the key that the
+    lru cache and the devledger count builds by."""
+    import jax
 
     # donating the arena lets XLA update in place instead of copying
     # the whole buffer per insert
-    return jax.jit(insert, donate_argnums=(0,))
+    return jax.jit(_write_slot, donate_argnums=(0,))
 
 
 class DeviceBlobArena:
@@ -150,16 +187,25 @@ class DeviceBlobArena:
         self._next += pad
         return offset
 
+    def _insert_locked(self, key: bytes, n: int, dev) -> None:
+        """Place a staged chunk of an n-byte blob in a fresh slot (caller
+        holds the lock)."""
+        pad = _pad_len(n)
+        offset = self._alloc_locked(pad)
+        self._arena = _jitted_insert(_size_class(pad))(
+            self._arena, dev, offset, pad
+        )
+        self._offsets[key] = (offset, n)
+
     def _stage_chunk(self, data: bytes):
-        """Dispatch the padded blob bytes host→device (async DMA —
-        jax.device_put returns before the copy lands) with transfer
-        telemetry at site=arena.stage."""
+        """Dispatch the blob bytes, zero-padded to their size class,
+        host→device (async DMA — jax.device_put returns before the copy
+        lands) with transfer telemetry at site=arena.stage."""
         import numpy as np
 
         from celestia_tpu.ops import transfers
 
-        pad = _pad_len(len(data))
-        chunk = np.zeros((pad,), np.uint8)
+        chunk = np.zeros((_size_class(_pad_len(len(data))),), np.uint8)
         chunk[: len(data)] = np.frombuffer(data, np.uint8)
         return transfers.device_put_chunked(
             chunk, self._device, site="arena.stage"
@@ -185,9 +231,7 @@ class DeviceBlobArena:
         with self._lock:
             if key in self._offsets:
                 return key
-            offset = self._alloc_locked(pad)
-            self._arena = _jitted_insert(pad)(self._arena, dev, offset)
-            self._offsets[key] = (offset, len(data))
+            self._insert_locked(key, len(data), dev)
             self._publish_metrics()
             return key
 
@@ -237,10 +281,7 @@ class DeviceBlobArena:
             inserted = 0
             for key, data, dev in staged:
                 if dev is not None and key not in self._offsets:
-                    pad = _pad_len(len(data))
-                    offset = self._alloc_locked(pad)
-                    self._arena = _jitted_insert(pad)(self._arena, dev, offset)
-                    self._offsets[key] = (offset, len(data))
+                    self._insert_locked(key, len(data), dev)
                     inserted += 1
                 keys.append(key)
             self._publish_metrics()

@@ -5,6 +5,8 @@ assemble the square on device from metadata only. Byte parity with the
 host path is the whole contract — every test pins the assembled DAH
 against the host-computed one."""
 
+import math
+
 import numpy as np
 import pytest
 
@@ -15,8 +17,15 @@ from celestia_tpu import square as square_pkg
 from celestia_tpu.app import App
 from celestia_tpu.crypto import PrivateKey
 from celestia_tpu.node import Node
-from celestia_tpu.ops.blob_pool import DeviceBlobArena, blob_key
+from celestia_tpu.ops import blob_pool
+from celestia_tpu.ops.blob_pool import (
+    DeviceBlobArena,
+    _pad_len,
+    _size_class,
+    blob_key,
+)
 from celestia_tpu.shares import to_bytes
+from celestia_tpu.telemetry import metrics
 from celestia_tpu.tx import Fee, sign_tx
 from celestia_tpu.user import Signer
 from celestia_tpu.x.blob.types import estimate_gas, new_msg_pay_for_blobs
@@ -145,6 +154,97 @@ class TestArena:
             stop.set()
             t.join(timeout=5)
         assert not errors, errors
+
+
+class TestSizeClassInsert:
+    """The insert program is keyed by the slot's power-of-two size class
+    and still writes exactly the slot's 4 KB-rounded bytes: a host
+    mirror of every put pins the whole arena, byte for byte."""
+
+    @staticmethod
+    def _put_mirrored(arena, mirror, data):
+        key = arena.put(data)
+        off, n = arena.offset_of(key)
+        pad = _pad_len(n)
+        mirror[off : off + pad] = 0
+        mirror[off : off + n] = np.frombuffer(data, np.uint8)
+        assert np.array_equal(np.asarray(arena.arena), mirror)
+        return key, off, pad
+
+    @pytest.mark.parametrize(
+        "capacity", [16 * 4096, 96 * 1024, 1 << 20, 100_000 + 123]
+    )
+    def test_puts_write_exactly_their_slots(self, capacity):
+        arena = DeviceBlobArena(capacity_bytes=capacity)
+        half = arena._half
+        mirror = np.zeros(capacity, np.uint8)
+        rng = np.random.default_rng(capacity)
+        written = {}
+
+        def put(n):
+            data = rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+            key, off, pad = self._put_mirrored(arena, mirror, data)
+            written[key] = data
+            return off, pad
+
+        # mixed sizes: one byte, page-exact, non-power-of-two slots
+        for n in (1, 4096, 4097, 3 * 4096 + 5, 5 * 4096, 7 * 4096 - 1):
+            if _pad_len(n) < arena._base + half - arena._next:
+                put(n)
+        # a slot that ends exactly at the active half's end
+        rest = arena._base + half - arena._next
+        off, pad = put(rest - 3)
+        assert off + pad == half
+        # the next put flips to the other half
+        off, pad = put(4096 + 1)
+        assert off == half
+        # a slot that ends at the arena's last usable byte
+        rest = arena._base + half - arena._next
+        off, pad = put(rest)
+        assert off + pad == 2 * half == capacity - arena.tail_bytes
+        # flip back over the first half; its entries are evicted
+        off, _pad = put(2 * 4096 + 9)
+        assert off == 0
+        for _ in range(6):
+            put(int(rng.integers(1, min(half, 9 * 4096))))
+
+        for key, data in written.items():
+            loc = arena.offset_of(key)
+            if loc is None:
+                continue
+            off, n = loc
+            got = np.asarray(arena.arena[off : off + _pad_len(n)]).tobytes()
+            assert got == data + bytes(_pad_len(n) - n)
+        assert not np.asarray(arena.arena[2 * half :]).any()
+
+    def test_insert_programs_are_size_classes_built_once(self):
+        """≥ 30 slot sizes from 1 B to ~2 MB, then fresh blobs of the same
+        sizes in another order: no insert program is rebuilt, and no more
+        are built than the arena has size classes."""
+        blob_pool._jitted_insert.cache_clear()
+        arena = DeviceBlobArena(capacity_bytes=64 << 20)
+        pages = np.unique(np.geomspace(1, 480, 40).astype(int))
+        sizes = [1] + [int(p) * 4096 - 100 for p in pages[1:]]
+        assert len({_pad_len(n) for n in sizes}) >= 30
+        rng = np.random.default_rng(24)
+
+        def blobs():
+            return [rng.bytes(int(n)) for n in rng.permutation(sizes)]
+
+        for data in blobs():
+            arena.put(data)
+        before = metrics.get_counter(
+            "xla_rebuild_total", entry="blob_pool.insert")
+        second = blobs()
+        keys = arena.put_many(second)
+        assert metrics.get_counter(
+            "xla_rebuild_total", entry="blob_pool.insert") == before
+        built = blob_pool._jitted_insert.cache_info().currsize
+        assert built == len({_size_class(_pad_len(n)) for n in sizes})
+        assert built <= math.ceil(math.log2(arena._half / 4096)) + 1
+        for key, data in zip(keys, second):
+            off, n = arena.offset_of(key)
+            assert np.asarray(arena.arena[off : off + n]).tobytes() == data
 
 
 class TestAssembledRoots:

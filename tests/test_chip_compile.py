@@ -113,6 +113,26 @@ def test_sha256_words_65536_lanes(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("size_class", [4096, 2 << 20, 32 << 20])
+def test_blob_arena_insert_updates_in_place(one_chip, size_class):
+    """The node's 64 MB arena takes an insert of any size class in
+    place: the output aliases the donated arena, and no arena-sized
+    temporary is made."""
+    import jax
+    import jax.numpy as jnp
+
+    from celestia_tpu.ops import blob_pool
+
+    arena = _spec((64 << 20,), jnp.uint8, one_chip)
+    chunk = _spec((size_class,), jnp.uint8, one_chip)
+    scalar = _spec((), jnp.int32, one_chip)
+    c = jax.jit(blob_pool._write_slot, donate_argnums=(0,)).lower(
+        arena, chunk, scalar, scalar).compile()
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes == 64 << 20
+    assert mem.temp_size_in_bytes < size_class
+
+
 def test_extend_and_roots_only_default_spelling(one_chip, monkeypatch):
     """The jitted roots program as the chip would trace it: the fused
     decision is taken for a TPU backend, which the test (not the
