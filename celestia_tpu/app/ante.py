@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import math
 
-from celestia_tpu import appconsts
+from celestia_tpu import appconsts, tracing
 from celestia_tpu.appconsts import BOND_DENOM
 from celestia_tpu.shares.splitters import sparse_shares_needed
 from celestia_tpu.tx import Tx, sign_doc_bytes
@@ -166,30 +166,33 @@ class AnteHandler:
             raise ValueError(
                 f"missing required signatures from: {sorted(missing)}"
             )
-        for si, sig in zip(tx.signer_infos, tx.signatures):
-            ctx.gas_meter.consume(SIG_VERIFY_COST_SECP256K1, "ante verify: secp256k1")
-            if simulate:
-                continue
-            addr = bech32_address(si.public_key)
-            acc = self.accounts.get_account(addr)
-            if acc is None:
-                raise ValueError(f"account {addr} not found")
-            if not acc.pub_key:
-                acc.pub_key = si.public_key
-                self.accounts.set_account(acc)
-            if si.sequence != acc.sequence:
-                raise ValueError(
-                    f"account sequence mismatch: expected {acc.sequence}, got {si.sequence}"
+        # one span per tx, under whichever of CheckTx, FilterTxs,
+        # ProcessProposal or DeliverTx ran the ante
+        with tracing.span("ante.sig_verify", sigs=len(tx.signatures)):
+            for si, sig in zip(tx.signer_infos, tx.signatures):
+                ctx.gas_meter.consume(SIG_VERIFY_COST_SECP256K1, "ante verify: secp256k1")
+                if simulate:
+                    continue
+                addr = bech32_address(si.public_key)
+                acc = self.accounts.get_account(addr)
+                if acc is None:
+                    raise ValueError(f"account {addr} not found")
+                if not acc.pub_key:
+                    acc.pub_key = si.public_key
+                    self.accounts.set_account(acc)
+                if si.sequence != acc.sequence:
+                    raise ValueError(
+                        f"account sequence mismatch: expected {acc.sequence}, got {si.sequence}"
+                    )
+                doc = sign_doc_bytes(
+                    tx.body_bytes(), tx.auth_info_bytes(), ctx.chain_id, acc.account_number
                 )
-            doc = sign_doc_bytes(
-                tx.body_bytes(), tx.auth_info_bytes(), ctx.chain_id, acc.account_number
-            )
-            # lazy: signature checks need the cryptography wheel, but
-            # the App must import (DA-only proposal path) without it
-            from celestia_tpu.crypto import verify_signature
+                # lazy: signature checks need the cryptography wheel, but
+                # the App must import (DA-only proposal path) without it
+                from celestia_tpu.crypto import verify_signature
 
-            if not verify_signature(si.public_key, doc, sig):
-                raise ValueError("signature verification failed")
+                if not verify_signature(si.public_key, doc, sig):
+                    raise ValueError("signature verification failed")
 
     def _min_gas_pfb(self, ctx: Context, tx: Tx) -> None:
         """ref: x/blob/ante/ante.go MinGasPFBDecorator"""

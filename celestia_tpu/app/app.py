@@ -577,9 +577,26 @@ class App:
             return self._assembled_proposal_dah_locked(data_square, builder, k)
 
     def _assembled_proposal_dah_locked(self, data_square, builder, k: int):
+        from celestia_tpu.ops import extend_tpu
+
+        # the host packing, apart from the device program it feeds
+        with tracing.span("extend.assemble_pack") as sp:
+            packed = self._assemble_pack(data_square, builder, k)
+            if packed is None:
+                return None
+            sp.set(blobs=len(packed[3]), host_cells=len(packed[1]))
+        rows, cols = extend_tpu.assembled_roots(self.blob_pool.arena, *packed, k)
+        return da.DataAvailabilityHeader(
+            [r.tobytes() for r in rows], [c.tobytes() for c in cols]
+        )
+
+    def _assemble_pack(self, data_square, builder, k: int):
+        """The arena path's host side: the resident blobs' placement and
+        the deduplicated host cells, as extend_tpu.assembled_roots takes
+        them (after the arena); None when most blob bytes are not
+        resident."""
         import numpy as np
 
-        from celestia_tpu.ops import extend_tpu
         from celestia_tpu.ops.blob_pool import blob_key
         from celestia_tpu.shares.splitters import sparse_shares_needed
 
@@ -638,16 +655,12 @@ class App:
             ).reshape(len(unique_rows), appconsts.SHARE_SIZE)
         else:
             host_shares = np.zeros((0, appconsts.SHARE_SIZE), np.uint8)
-        rows, cols = extend_tpu.assembled_roots(
-            self.blob_pool.arena, host_shares, host_pos, host_row,
+        return (
+            host_shares, host_pos, host_row,
             np.array(blob_starts, np.int32), np.array(blob_ns, np.int32),
             np.array(blob_offs, np.int32), np.array(blob_lens, np.int32),
             np.stack(ns_rows) if ns_rows
             else np.zeros((0, appconsts.NAMESPACE_SIZE), np.uint8),
-            k,
-        )
-        return da.DataAvailabilityHeader(
-            [r.tobytes() for r in rows], [c.tobytes() for c in cols]
         )
 
     def _extend_and_hash(self, data_square) -> tuple:
@@ -808,23 +821,27 @@ class App:
         re-validated here too: a proposer handed an unchecked tx with a
         tampered blob would otherwise build a proposal its own
         ProcessProposal rejects — a liveness footgun for zero safety
-        benefit. The recompute is cheap next to the square extend."""
+        benefit. The recompute is cheap next to the square extend.
+
+        The call is one ``app.filter_txs`` span (attrs ``txs``, ``kept``)."""
         ante = self._ante()
         kept_normal: list[bytes] = []
         kept_blob: list[bytes] = []
-        for raw in txs:
-            btx, is_blob = blob_pkg.unmarshal_blob_tx(raw)
-            inner = btx.tx if is_blob else raw
-            try:
-                tx = validate_blob_tx(btx) if is_blob else decode_tx(inner)
-                if not is_blob and any(
-                    isinstance(m, MsgPayForBlobs) for m in tx.msgs
-                ):
-                    continue  # bare PFB: ProcessProposal would reject it
-                ante(ctx, tx, len(inner))
-            except Exception:  # noqa: BLE001
-                continue
-            (kept_blob if is_blob else kept_normal).append(raw)
+        with tracing.span("app.filter_txs", txs=len(txs)) as sp:
+            for raw in txs:
+                btx, is_blob = blob_pkg.unmarshal_blob_tx(raw)
+                inner = btx.tx if is_blob else raw
+                try:
+                    tx = validate_blob_tx(btx) if is_blob else decode_tx(inner)
+                    if not is_blob and any(
+                        isinstance(m, MsgPayForBlobs) for m in tx.msgs
+                    ):
+                        continue  # bare PFB: ProcessProposal would reject it
+                    ante(ctx, tx, len(inner))
+                except Exception:  # noqa: BLE001
+                    continue
+                (kept_blob if is_blob else kept_normal).append(raw)
+            sp.set(kept=len(kept_normal) + len(kept_blob))
         return kept_normal + kept_blob
 
     # ------------------------------------------------------------------ #

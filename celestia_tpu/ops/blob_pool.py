@@ -190,11 +190,14 @@ class DeviceBlobArena:
     def _insert_locked(self, key: bytes, n: int, dev) -> None:
         """Place a staged chunk of an n-byte blob in a fresh slot (caller
         holds the lock)."""
+        from celestia_tpu.telemetry import metrics
+
         pad = _pad_len(n)
         offset = self._alloc_locked(pad)
         self._arena = _jitted_insert(_size_class(pad))(
             self._arena, dev, offset, pad
         )
+        metrics.incr_counter("blob_arena_insert_calls_total")
         self._offsets[key] = (offset, n)
 
     def _stage_chunk(self, data: bytes):

@@ -62,9 +62,9 @@ def _rebuilds() -> float:
 
 
 def test_broadcast_tx_span_tree_and_insert_rebuild(node):
-    """node.broadcast_tx > {app.check_tx > blob.validate, blob_pool.stage};
-    a rebuilt insert program is an xla.compile under blob_pool.stage and
-    one xla_rebuild_total."""
+    """node.broadcast_tx > {app.check_tx > {blob.validate, ante.sig_verify},
+    blob_pool.stage}; a rebuilt insert program is an xla.compile under
+    blob_pool.stage and one xla_rebuild_total."""
     raw = _blob_tx(0, 5000)
     with tracing.record() as rec:
         assert node.broadcast_tx(raw).code == 0
@@ -75,9 +75,10 @@ def test_broadcast_tx_span_tree_and_insert_rebuild(node):
     kids = {s.name: s for s in _children(rec.spans, root)}
     assert set(kids) == {"app.check_tx", "blob_pool.stage"}
     assert kids["app.check_tx"].attrs == {"recheck": False, "code": 0}
-    (val,) = _children(rec.spans, kids["app.check_tx"])
+    val, sig = _children(rec.spans, kids["app.check_tx"])
     assert val.name == "blob.validate"
     assert val.attrs == {"blobs": 1, "bytes": 5000}
+    assert sig.name == "ante.sig_verify" and sig.attrs == {"sigs": 1}
     stage = kids["blob_pool.stage"].attrs
     assert stage == {"blobs": 1, "staged": 1, "resident_hits": 0}
 
@@ -189,3 +190,63 @@ def test_extend_programs_are_named_after_their_entry(entry, builder, args,
     # the benchmark's extend metrics match the family by this pattern
     assert re.search(r"^jit_run\b", name)
     assert re.search(rf"^jit_run\.{re.escape(entry)}\b", name + "(3)")
+
+
+def _multi_blob_tx(seq: int, sizes: list[int]) -> bytes:
+    addr = KEY.bech32_address()
+    rng = np.random.default_rng(100 + seq)
+    blobs = [blob_pkg.new_blob(ns.new_v0(b"multi" + bytes([seq, j])),
+                               rng.integers(0, 256, s, np.uint8).tobytes(), 0)
+             for j, s in enumerate(sizes)]
+    gas = estimate_gas(sizes)
+    tx = sign_tx(KEY, [new_msg_pay_for_blobs(addr, *blobs)], CHAIN, 0, seq,
+                 Fee(amount=gas, gas_limit=gas))
+    return blob_pkg.marshal_blob_tx(tx.marshal(), blobs)
+
+
+def _ancestors(spans, span):
+    by_id = {s.span_id: s for s in spans}
+    names = []
+    while span.parent_id is not None:
+        span = by_id[span.parent_id]
+        names.append(span.name)
+    return names
+
+
+def test_per_tx_and_per_blob_spans_and_insert_counter(node):
+    """Two multi-blob PFBs through admission and one block: the ante's
+    signature check under each of its four callers, the proposer's tx
+    filter, the arena path's host packing, and one insert dispatch per
+    blob staged."""
+    txs = [_multi_blob_tx(0, [700, 1800, 3000]), _multi_blob_tx(1, [900, 2500])]
+    before = metrics.get_counter("blob_arena_insert_calls_total")
+    with tracing.record() as rec:
+        for raw in txs:
+            assert node.broadcast_tx(raw).code == 0
+        block = node.produce_block(30.0)
+    assert len(block.txs) == 2
+    (stage_a, stage_b) = [s for s in rec.spans if s.name == "blob_pool.stage"]
+    staged = stage_a.attrs["staged"] + stage_b.attrs["staged"]
+    assert staged == 5
+    assert metrics.get_counter("blob_arena_insert_calls_total") == before + staged
+
+    sigs = [s for s in rec.spans if s.name == "ante.sig_verify"]
+    assert all(s.attrs == {"sigs": 1} for s in sigs)
+    callers = [next(a for a in _ancestors(rec.spans, s)
+                    if a in ("app.check_tx", "app.filter_txs",
+                             "app.process_proposal", "app.deliver_tx"))
+               for s in sigs]
+    assert sorted(callers) == sorted(["app.check_tx", "app.filter_txs",
+                                      "app.process_proposal",
+                                      "app.deliver_tx"] * 2)
+
+    (filt,) = [s for s in rec.spans if s.name == "app.filter_txs"]
+    assert filt.attrs == {"txs": 2, "kept": 2}
+    assert _ancestors(rec.spans, filt)[0] == "app.prepare_proposal"
+
+    packs = [s for s in rec.spans if s.name == "extend.assemble_pack"]
+    assert len(packs) == 2  # Prepare and Process
+    for sp in packs:
+        assert _ancestors(rec.spans, sp)[0] == "extend.block"
+        assert sp.attrs["blobs"] == 5
+        assert 0 < sp.attrs["host_cells"] < block.square_size ** 2
