@@ -821,7 +821,9 @@ class App:
         re-validated here too: a proposer handed an unchecked tx with a
         tampered blob would otherwise build a proposal its own
         ProcessProposal rejects — a liveness footgun for zero safety
-        benefit. The recompute is cheap next to the square extend.
+        benefit. The pass reads each blob's commitment from the parsed
+        blob, where CheckTx left it (x/blob/types.validate_blob_tx), so
+        a tx this node admitted costs no recompute.
 
         The call is one ``app.filter_txs`` span (attrs ``txs``, ``kept``)."""
         ante = self._ante()

@@ -186,19 +186,29 @@ def new_msg_pay_for_blobs(signer: str, *blobs: blob_pkg.Blob) -> MsgPayForBlobs:
 
 
 def validate_blob_tx(btx: blob_pkg.BlobTx, sdk_tx=None):
-    """Stateless BlobTx<->PFB consistency + commitment recompute.
+    """Stateless BlobTx<->PFB consistency + commitment check.
 
     Accepts (and returns) the decoded inner Tx so hot-path callers that
-    already decoded it don't pay a second protobuf parse.
+    already decoded it don't pay a second protobuf parse. Each blob's
+    commitment is computed once and kept on the Blob object, which
+    blob.unmarshal_blob_tx's parse cache hands to CheckTx, the
+    proposer's filter and ProcessProposal alike; every call still
+    compares it with the PFB's declared one. The span's `hits` attr
+    counts the blobs whose commitment an earlier call had computed,
+    when any were.
     ref: x/blob/types/blob_tx.go:36-103"""
     with tracing.span("blob.validate") as sp:
         if tracing.enabled():
             sp.set(blobs=len(btx.blobs),
                    bytes=sum(len(b.data) for b in btx.blobs))
-        return _validate_blob_tx(btx, sdk_tx)
+        sdk_tx, hits = _validate_blob_tx(btx, sdk_tx)
+        if hits and tracing.enabled():
+            sp.set(hits=hits)
+        return sdk_tx
 
 
 def _validate_blob_tx(btx: blob_pkg.BlobTx, sdk_tx):
+    """(decoded tx, blobs whose commitment an earlier call computed)."""
     from celestia_tpu.tx import Tx
 
     if sdk_tx is None:
@@ -224,11 +234,31 @@ def _validate_blob_tx(btx: blob_pkg.BlobTx, sdk_tx):
         if blob_ns.bytes != pfb_ns.bytes:
             raise ValueError("namespace mismatch between blob and PFB")
 
+    hits = 0
     for i, commitment in enumerate(msg.share_commitments):
-        calculated = inclusion.create_commitment(btx.blobs[i])
+        calculated, hit = _share_commitment(btx.blobs[i])
+        hits += hit
         if calculated != commitment:
             raise ValueError("invalid share commitment")
-    return sdk_tx
+    return sdk_tx, hits
+
+
+def _share_commitment(b: blob_pkg.Blob) -> tuple[bytes, bool]:
+    """(share commitment of `b`, whether an earlier call computed it).
+    The commitment is a pure function of the blob's fields, and parsed
+    blobs are never changed (blob.unmarshal_blob_tx), so it is kept on
+    the object as the splitter keeps its shares (`_sparse_shares`), and
+    lives as long as the parse cache holds the blob. Each call counts
+    one `blob_commitment_lookups_total{result="hit"|"miss"}`."""
+    from celestia_tpu.telemetry import metrics
+
+    found = getattr(b, "_commitment", None)
+    if found is not None:
+        metrics.incr_counter("blob_commitment_lookups_total", result="hit")
+        return found, True
+    metrics.incr_counter("blob_commitment_lookups_total", result="miss")
+    b._commitment = inclusion.create_commitment(b)
+    return b._commitment, False
 
 
 def pfb_blob_sizes(inner_tx: bytes) -> list[int]:
