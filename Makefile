@@ -13,7 +13,7 @@ PY ?= python
 	scenario-disk-pressure scenarios \
 	soak-smoke scenario-soak scenario-das-sweep \
 	kernel-smoke bench-fused analyze san multichip-smoke multichip-bench \
-	xor-smoke bench-xor devledger-smoke
+	devledger-smoke
 
 # Static analysis gate (specs/analysis.md, ADR-020): AST-level
 # concurrency lint (lock ordering vs the specs/serving.md partial
@@ -110,7 +110,7 @@ trace-smoke:
 # Perf-regression gate (specs/slo.md, ADR-014): judge the tracked series
 # vs their median±MAD baselines; exits non-zero with a readable table on
 # a regression. The bench walls (extend, repair, node-path, transfer,
-# fused, xor) come from BENCH_r*.json rounds, none of which is committed
+# fused) come from BENCH_r*.json rounds, none of which is committed
 # since PR 21: they are inert until the benchmark PR feeds them from the
 # driver's PERF_LEDGER.jsonl. The storm/scenario/soak series still gate.
 # Pure ledger math, never touches the accelerator.
@@ -236,36 +236,18 @@ fleet-smoke:
 
 # Fused-kernel smoke gate (ADR-019): fused extend+hash DAH byte-parity
 # vs the host oracle at k ∈ {32, 64} (production dispatch + the
-# kernels' eager reference math), the committed crossover table picking
-# TPU at the governance-default k=64 on measured numbers with safe
-# degradation off dead backends, and vmappable batched-roots chunking
+# kernels' eager reference math), `auto` picking TPU at every k with an
+# accelerator and degrading off a dead one, and vmappable batched-roots chunking
 # at k=128. CPU-only, crypto-free, <120 s (repeat runs much faster via
 # the persistent XLA compile cache).
 kernel-smoke:
 	JAX_PLATFORMS=cpu $(PY) scripts/kernel_smoke.py
-
-# XOR-schedule smoke gate (ADR-024): sparse-schedule vs dense GF(2)
-# bit-matmul byte-parity at k ∈ {4, 16, 32}, DAH parity through the
-# production roots path with the schedule forced on, one jit cache
-# entry per (k, spelling), and CELESTIA_XOR_SCHEDULE override
-# semantics (0 pins dense over any table, 1 forces xor, non-pow2 k
-# always refuses). CPU-only, crypto-free, <120 s (repeat runs much
-# faster via the persistent XLA compile cache).
-xor-smoke:
-	JAX_PLATFORMS=cpu $(PY) scripts/xor_smoke.py
 
 # The ADR-019 step-change configs alone on the real chip: fused
 # roots-only vs the XLA roots path vs native at k ∈ {64, 32}; prints
 # one JSON line (nothing persists it since PR 21).
 bench-fused:
 	$(PY) bench.py --fused-kernels
-
-# The ADR-024 A/B alone: sparse XOR schedule vs the dense bit-matmul
-# inside the same fused hash pipeline at k ∈ {64, 32}; prints one JSON
-# line (nothing persists it since PR 21).
-# Add --write-table to refresh config/xor_schedule.json.
-bench-xor:
-	$(PY) bench.py --xor-schedule
 
 # Scenario-engine smoke gate (specs/scenarios.md, ADR-018): run the
 # condensed `smoke` scenario twice on one seed, pin an identical fault

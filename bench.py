@@ -512,8 +512,7 @@ def bench_fused_kernels(k: int):
     pipeline vs the XLA roots path vs the native-CPU baseline at one k.
     The fused spelling keeps parity planes + leaf messages in VMEM and
     returns 90-byte NMT axis roots — HBM never sees the unpacked
-    message tensor — so this is the number that decides the k=64
-    crossover. Parity is gated against the host DAH (byte compare of
+    message tensor. Parity is gated against the host DAH (byte compare of
     every row/col root)."""
     import jax
 
@@ -572,68 +571,6 @@ def bench_fused_kernels(k: int):
         ),
         "parity": bool(parity),
     }
-
-
-def bench_xor_schedule(k: int):
-    """Config 13 (ADR-024): the sparse CSE-shared XOR-schedule
-    contraction vs the dense GF(2) bit-matmul, A/B'd through the SAME
-    jitted roots-only core the proposal path runs (the spelling pinned
-    via _jitted_roots_noeds(k, xor=...); everything downstream of the
-    contraction is shared). Both spellings are plain XLA programs, so
-    this config measures on ANY backend — the crossover is a property
-    of the contraction, and config/xor_schedule.json persists whichever
-    spelling measured faster. Parity is gated against the host DAH."""
-    import jax
-
-    from celestia_tpu import da
-    from celestia_tpu.ops import extend_tpu, xor_schedule
-
-    if not xor_schedule.supported(k):
-        return {"skipped": f"xor schedule unsupported at k={k}"}
-
-    sq = build_square(k)
-    devs = [jax.device_put(build_square(k, seed=100 + i)) for i in range(4)]
-    xor_fn = extend_tpu._jitted_roots_noeds(k, xor=True)
-    dense_fn = extend_tpu._jitted_roots_noeds(k, xor=False)
-
-    def fetch(r):
-        return np.asarray(r[0])
-
-    # sample counts scale down with k: on XLA:CPU a k=64 square costs
-    # seconds per dispatch, and _slope's default tries×(n1+n2) squares
-    # per arm would blow the 600 s config watchdog
-    n1, n2, tries = (4, 24, 3) if k <= 32 else (2, 8, 2)
-    xor_ms = _slope(lambda i: xor_fn(devs[i % 4]), fetch,
-                    n1=n1, n2=n2, tries=tries)
-    dense_ms = _slope(lambda i: dense_fn(devs[i % 4]), fetch,
-                      n1=n1, n2=n2, tries=tries)
-
-    rows_x, cols_x = (np.asarray(a) for a in xor_fn(jax.device_put(sq)))
-    eds_ref = da.extend_shares(sq.reshape(k * k, 512))
-    dah_ref = da.new_data_availability_header(eds_ref)
-    parity = (
-        [bytes(r) for r in rows_x] == dah_ref.row_roots
-        and [bytes(c) for c in cols_x] == dah_ref.column_roots
-    )
-    out = {
-        "square_size": k,
-        "jax_backend": jax.default_backend(),
-        "xor_ms_per_square": round(xor_ms, 3) if xor_ms > 0 else None,
-        "dense_ms_per_square": round(dense_ms, 3) if dense_ms > 0 else None,
-        "xor_vs_dense_speedup": (
-            round(dense_ms / xor_ms, 2)
-            if xor_ms > 0 and dense_ms > 0 else None
-        ),
-        "winner": (
-            ("xor" if xor_ms < dense_ms else "dense")
-            if xor_ms > 0 and dense_ms > 0 else None
-        ),
-        "parity": bool(parity),
-    }
-    # schedule shape next to the walls (the _stamp_host discipline:
-    # numbers carry enough context to be questioned later)
-    out.update(xor_schedule.schedule_stats(k))
-    return out
 
 
 def bench_node_path(k: int):
@@ -2616,61 +2553,6 @@ def main_fused_kernels():
     _finish(headline, configs, prov, "fused-path DAH mismatch vs host")
 
 
-def main_xor_schedule():
-    """`python bench.py --xor-schedule [--write-table]`: the ADR-024
-    A/B — sparse XOR-schedule contraction vs dense GF(2) bit-matmul
-    through the jitted roots-only core at k ∈ {64, 32}. Unlike
-    --fused-kernels this measures on ANY backend (both spellings are
-    XLA programs). --write-table refreshes config/xor_schedule.json
-    from the fresh measurements so `auto` routing (_xor_active) picks
-    the measured winner per k. Exits non-zero on a parity failure or
-    when a config throws."""
-    from celestia_tpu.ops import enable_compile_cache
-
-    enable_compile_cache()
-    name = "13_xor_schedule_k64"
-    metric = "xor_schedule_ms_per_square_k64"
-    configs: dict = {}
-    prov: dict = {}
-    _run_config(configs, prov, name, bench_xor_schedule, 64)
-    _run_config(configs, prov, "13b_xor_schedule_k32",
-                bench_xor_schedule, 32)
-    head = configs.get(name) or {}
-    headline = {
-        "metric": metric,
-        "value": head.get("xor_ms_per_square"),
-        "unit": "ms",
-        "vs_baseline": head.get("xor_vs_dense_speedup"),
-        "dense_baseline_ms": head.get("dense_ms_per_square"),
-        "winner": head.get("winner"),
-        "parity": head.get("parity"),
-    }
-
-    if "--write-table" in sys.argv:
-        from celestia_tpu.app import calibration
-
-        entries = {
-            cfg["square_size"]: {
-                "dense": cfg["dense_ms_per_square"],
-                "xor": cfg["xor_ms_per_square"],
-            }
-            for n, cfg in configs.items()
-            if prov.get(n) == "measured"
-            and isinstance(cfg, dict)
-            and cfg.get("dense_ms_per_square")
-            and cfg.get("xor_ms_per_square")
-        }
-        if entries:
-            table = calibration.CrossoverTable(entries,
-                                               measured_at=time.time())
-            path = (pathlib.Path(__file__).resolve().parent / "config"
-                    / calibration.XOR_FILENAME)
-            table.save(path)
-            print(f"xor crossover table written: {path}", file=sys.stderr)
-
-    _finish(headline, configs, prov, "xor-schedule DAH mismatch vs dense")
-
-
 def main_transfers():
     """`make bench-transfers` / `python bench.py --transfers`: the
     sliced-read and k=64 node-path configs with the fault injector ARMED
@@ -2866,8 +2748,6 @@ if __name__ == "__main__":
             main_transfers()
         elif "--fused-kernels" in sys.argv:
             main_fused_kernels()
-        elif "--xor-schedule" in sys.argv:
-            main_xor_schedule()
         else:
             main()
     finally:

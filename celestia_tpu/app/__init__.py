@@ -1,10 +1,8 @@
 """The application layer (ABCI boundary).
 
 App/Context re-exports are LAZY (PEP 562): `app.app` pulls the full
-state-machine import chain (crypto, x/ modules), but light submodules —
-`app.calibration`, used by bench and the transfer tests — must stay
-importable without it (the crossover table itself is pure stdlib +
-numpy, and should load even where the `cryptography` wheel is absent).
+state-machine import chain (crypto, x/ modules), so importing a light
+submodule of the package must not drag it in.
 """
 
 _EXPORTS = {

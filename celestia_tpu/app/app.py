@@ -110,14 +110,6 @@ class ProposalBlockData:
     hash: bytes
 
 
-# Static crossover FALLBACK for the auto backend (bench config 1 vs 2):
-# at k=2 the device path is dispatch-bound (0.18x native), at k=32 it is
-# ~50x. Below this square size "auto" stays on the native CPU runtime.
-# A node with a measured CrossoverTable (app/calibration.py, ADR-012)
-# overrides this guess with per-k measured winners — the static gate
-# only governs uncalibrated processes (tests, fresh homes, libraries).
-TPU_MIN_SQUARE = 16
-
 _accel_probe: bool | None = None
 
 
@@ -178,14 +170,6 @@ class App:
         self.sdc_quarantined = False
         self.sdc_events = 0
         self.last_sdc: dict | None = None
-        # measured per-k backend crossover (app/calibration.py); starts
-        # from the repo-committed default table so `auto` routes on
-        # measured numbers out of the box (ADR-019) — a node-home table
-        # or calibrate_crossover() overrides it, and None (no committed
-        # file) falls back to the static TPU_MIN_SQUARE gate
-        from celestia_tpu.app import calibration
-
-        self.crossover = calibration.load_default_table()
         self.blob_pool = None  # device blob arena (enable_blob_pool)
         # assembled-vs-fallback proposal counts when the arena is on
         self.arena_stats = {"assembled": 0, "fallback": 0}
@@ -301,28 +285,19 @@ class App:
     def resolve_extend_backend(self, k: int) -> str:
         """Pick the live ExtendBlock backend for a k×k square.
 
-        auto: the MEASURED winner for this k when a CrossoverTable is
-        attached (self.crossover, app/calibration.py — winners are
-        re-checked against live backend availability, so a table
-        measured elsewhere degrades safely); otherwise the static gate —
-        device when an accelerator is present and k >= TPU_MIN_SQUARE,
-        else the native C++ runtime, else numpy. Explicit backends are
-        honored ("tpu" means the jax device path on whatever backend jax
-        has — the CPU-mesh tests exercise it without hardware). All
-        backends are byte-identical (pinned by tests + the DAH oracles),
-        so the choice is purely a latency call."""
+        auto: the device when an accelerator is present, else the
+        native C++ runtime, else numpy — at every k. Explicit backends
+        are honored ("tpu" means the jax device path on whatever backend
+        jax has — the CPU-mesh tests exercise it without hardware);
+        "native" without the C++ runtime falls to numpy, and a device
+        that struck out (_tpu_disabled) falls to native, then numpy.
+        All backends are byte-identical (pinned by tests + the DAH
+        oracles), so the choice is purely a latency call."""
         from celestia_tpu import native
 
         backend = self.extend_backend
         if backend == "auto":
-            winner = self.crossover.winner(k) if self.crossover else None
-            if winner == "tpu" and not accelerator_available():
-                winner = None
-            if winner == "native" and not native.available():
-                winner = None
-            if winner is not None:
-                backend = winner
-            elif accelerator_available() and k >= TPU_MIN_SQUARE:
+            if accelerator_available():
                 backend = "tpu"
             elif native.available():
                 backend = "native"
@@ -338,23 +313,6 @@ class App:
                      configured=self.extend_backend)
             self._active_backend = backend
         return backend
-
-    def calibrate_crossover(self, ks: tuple[int, ...] | None = None,
-                            repeats: int = 2, persist_path=None):
-        """Measure the per-k TPU/native latency table and attach it, so
-        `auto` resolves to the measured winner (app/calibration.py,
-        ADR-012). Refreshable at any time; persists to JSON when a path
-        is given (cli start loads it back on the next boot)."""
-        from celestia_tpu.app import calibration
-
-        table = calibration.measure_crossover(
-            ks or calibration.DEFAULT_KS, repeats
-        )
-        self.crossover = table
-        self._active_backend = None  # re-log the (possibly new) winner
-        if persist_path is not None:
-            table.save(persist_path)
-        return table
 
     def _square_array(self, data_square, k: int):
         import numpy as np

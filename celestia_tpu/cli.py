@@ -132,19 +132,6 @@ def cmd_start(args):
     node.app.min_gas_price = cfg.app.min_gas_price
     node.mempool.ttl_blocks = cfg.consensus.mempool.ttl_num_blocks
     node.mempool.max_tx_bytes = cfg.consensus.mempool.max_tx_bytes
-    # calibrated auto crossover (app/calibration.py, ADR-012): load the
-    # persisted per-k table when present; measure + persist a fresh one
-    # when configured or asked (--calibrate-crossover refreshes a stale
-    # table, e.g. after the hardware changed)
-    from celestia_tpu.app.calibration import CrossoverTable, crossover_path
-
-    cal_path = crossover_path(home)
-    table = CrossoverTable.load(cal_path)
-    if table is not None:
-        node.app.crossover = table
-    if cfg.app.calibrate_crossover or getattr(args, "calibrate_crossover",
-                                              False):
-        node.app.calibrate_crossover(persist_path=cal_path)
     # resolve + log the live backend up front so the operator sees what
     # this node will actually run on the hot path
     live = node.boot_extend_backend()
@@ -627,11 +614,6 @@ def main(argv=None):
                          choices=["auto", "tpu", "native", "numpy"],
                          help="ExtendBlock backend (default: config "
                               "app.extend_backend, 'auto')")
-    p_start.add_argument("--calibrate-crossover", action="store_true",
-                         help="measure the per-k TPU/native latency "
-                              "crossover now and persist it to "
-                              "config/crossover.json ('auto' then picks "
-                              "the measured winner per square size)")
     p_start.add_argument("--log-level", default="info",
                          choices=["debug", "info", "warning", "error"])
     p_start.add_argument("--trace-out", default=None, metavar="PATH",

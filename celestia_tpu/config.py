@@ -90,17 +90,11 @@ class AppConfig:
     grpc_enable: bool = False
     grpc_web_enable: bool = False
     # ExtendBlock backend: auto | tpu | native | numpy. "auto" picks the
-    # accelerator when a device is present AND the square is above the
-    # measured dispatch-bound crossover (app.app.TPU_MIN_SQUARE), else the
-    # native C++ runtime, else numpy. This framework's analogue of the
-    # reference selecting its codec at pkg/appconsts/global_consts.go:92.
+    # accelerator when a device is present, else the native C++ runtime,
+    # else numpy (App.resolve_extend_backend). This framework's analogue
+    # of the reference selecting its codec at
+    # pkg/appconsts/global_consts.go:92.
     extend_backend: str = "auto"
-    # Measure the per-k TPU/native crossover at startup and persist the
-    # table to config/crossover.json (app/calibration.py, ADR-012).
-    # Default off: a persisted table (from a previous calibrated start
-    # or `--calibrate-crossover`) is loaded either way, so steady-state
-    # boots never pay the measurement.
-    calibrate_crossover: bool = False
     state_sync: StateSyncConfig = dataclasses.field(default_factory=StateSyncConfig)
 
 

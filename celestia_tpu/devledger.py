@@ -12,7 +12,7 @@ drift judge, and the scenario verdicts. This module is that watcher,
 three planes in one leaf-locked object:
 
 1. **Compile/retrace watchdog.** Every jitted-entry builder in
-   ops/{extend_tpu,ragged,rs_pallas,xor_schedule,transfers,blob_pool}
+   ops/{extend_tpu,ragged,rs_pallas,transfers,blob_pool}
    is wrapped with `instrument_builder(entry)` placed BETWEEN the
    builder's ``functools.lru_cache`` and its body, so the watchdog sees
    exactly the lru misses — one call per distinct shape/dtype/mesh key.

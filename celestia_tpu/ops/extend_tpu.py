@@ -35,7 +35,6 @@ reference DAH.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -51,17 +50,12 @@ from celestia_tpu.appconsts import (
     SHARE_SIZE,
 )
 from celestia_tpu.ops import rs_tpu
-# The pipeline's hasher is the XLA scan spelling. A Pallas alternative
-# exists (ops/sha256_pallas.py) and measures 1.8x FASTER standalone on
-# the k=128 leaf workload (3.0 vs 5.5 ms for 65k x 571 B messages) —
-# but swapping it into THIS fused pipeline measured SLOWER end-to-end
-# (k=128 extend 5.97 vs 4.98 ms, NMT-only 4.02 vs 2.7 ms): the
-# pallas_call boundary forces the padded/transposed message tensor
-# (~38 MB) to materialize in HBM, while XLA fuses leaf construction
-# straight into the hash rounds and never builds it. Same lesson as
-# ops/rs_pallas (see its docstring): on this pipeline, fusion beats
-# hand-tiling — both kernels stay as explicitly-invoked, bit-exact
-# alternatives for workloads that feed from HBM anyway.
+# The pipeline's hasher is the XLA scan spelling, not the Pallas kernel
+# in ops/sha256_pallas.py: a pallas_call boundary forces the padded,
+# transposed leaf-message tensor to materialise in HBM, while XLA fuses
+# leaf construction straight into the hash rounds and never builds it.
+# Same lesson as ops/rs_pallas (see its docstring): on this pipeline,
+# fusion beats hand-tiling.
 from celestia_tpu.ops.sha256_jax import sha256_fixed, words_to_bytes
 
 _PARITY_NS = np.frombuffer(ns.PARITY_SHARES_NAMESPACE.bytes, dtype=np.uint8)
@@ -70,51 +64,16 @@ _PARITY_NS = np.frombuffer(ns.PARITY_SHARES_NAMESPACE.bytes, dtype=np.uint8)
 # roots pipeline runs ops/rs_pallas.encode2d_hash — parity bytes AND
 # NMT leaf digests leave each kernel invocation together, so neither
 # the unpacked bit planes nor the padded leaf-message tensor ever
-# round-trips through HBM. "0"/"off" pins the XLA spelling (A/B
-# benching, bisection); "1"/"on" forces the kernels even on the CPU
-# backend — device-backend experiments only: Mosaic does not lower on
-# XLA:CPU and the unrolled SHA graph takes minutes to compile there.
-# The decision is frozen into each jit cache entry at first trace.
-_FUSED_ENV = "CELESTIA_FUSED_KERNELS"
-
-
+# round-trips through HBM. The CPU backend and shapes the kernels do
+# not tile take the dense XLA spelling. The decision is frozen into
+# each jit cache entry at first trace.
 def _fused_active(k: int) -> bool:
     from celestia_tpu.ops import rs_pallas
 
-    v = os.environ.get(_FUSED_ENV, "").strip().lower()
-    if v in ("0", "off", "false"):
-        return False
-    if not rs_pallas.fused_supported(k, k * SHARE_SIZE):
-        return False
-    if v in ("1", "on", "true"):
-        return True
-    return jax.default_backend() not in ("cpu",)
+    return (jax.default_backend() != "cpu"
+            and rs_pallas.fused_supported(k, k * SHARE_SIZE))
 
 
-# XOR-schedule contraction (ADR-024): per-k choice between the dense
-# GF(2) bit-matmul and the sparse CSE-shared XOR schedule, resolved
-# from the measured A/B table (config/xor_schedule.json, bench.py
-# --xor-schedule) — the two spellings are byte-identical, so this is
-# purely a perf decision. "0"/"off" pins dense, "1"/"on" pins the
-# schedule; default consults the table (absent/unmeasured -> dense).
-# Like _fused_active, the decision freezes into each jit cache entry
-# at first trace.
-_XOR_ENV = "CELESTIA_XOR_SCHEDULE"
-
-
-def _xor_active(k: int) -> bool:
-    from celestia_tpu.ops import xor_schedule
-
-    v = os.environ.get(_XOR_ENV, "").strip().lower()
-    if v in ("0", "off", "false"):
-        return False
-    if not xor_schedule.supported(k):
-        return False
-    if v in ("1", "on", "true"):
-        return True
-    from celestia_tpu.app import calibration
-
-    return calibration.xor_winner(k) == "xor"
 _LEAF_PREFIX = np.array([0], dtype=np.uint8)
 _NODE_PREFIX = np.array([1], dtype=np.uint8)
 NMT_NODE_SIZE = 2 * NAMESPACE_SIZE + 32  # 90
@@ -232,7 +191,7 @@ def _digest_grid_roots(digest_bytes: jnp.ndarray, leaf_ns: jnp.ndarray):
 
 
 def _roots_of_fused(shares: jnp.ndarray, m2: jnp.ndarray,
-                    interpret: bool = False, xor: bool = False):
+                    interpret: bool = False):
     """The Pallas spelling of _roots_of (ADR-019): the three quadrant
     encodes run ops/rs_pallas.encode2d_hash, so every parity cell's NMT
     leaf digest is computed in VMEM next to the pack stage; Q0 cells go
@@ -244,17 +203,6 @@ def _roots_of_fused(shares: jnp.ndarray, m2: jnp.ndarray,
     digest grids transpose with it)."""
     from celestia_tpu.ops import rs_pallas
 
-    if xor:
-        # Same fused pipeline, XOR-schedule contraction (ADR-024): the
-        # hash stage and output contract are shared with the dense
-        # kernel, so only the encode spelling changes.
-        from celestia_tpu.ops import xor_schedule
-
-        def _enc(x, _m2, inter):
-            return xor_schedule.encode2d_xor_hash(x, inter)
-    else:
-        _enc = rs_pallas.encode2d_hash
-
     k = shares.shape[0]
     n = k * SHARE_SIZE
     x0 = shares.reshape(k, n)
@@ -262,13 +210,13 @@ def _roots_of_fused(shares: jnp.ndarray, m2: jnp.ndarray,
     d0 = rs_pallas.leaf_digests2d(
         x0, rs_pallas.pad_namespaces(q0_ns), interpret
     )  # (k, k, 8): [row, col]
-    q2f, d2 = _enc(x0, m2, interpret)  # native: [row, col]
+    q2f, d2 = rs_pallas.encode2d_hash(x0, m2, interpret)  # native: [row, col]
     q2 = q2f.reshape(k, k, SHARE_SIZE)
     x0t = jnp.swapaxes(shares, 0, 1).reshape(k, n)
-    q1t, d1t = _enc(x0t, m2, interpret)  # [col, row]
+    q1t, d1t = rs_pallas.encode2d_hash(x0t, m2, interpret)  # [col, row]
     q1 = jnp.swapaxes(q1t.reshape(k, k, SHARE_SIZE), 0, 1)
     q2t = jnp.swapaxes(q2, 0, 1).reshape(k, n)
-    q3t, d3t = _enc(q2t, m2, interpret)  # [col, row]
+    q3t, d3t = rs_pallas.encode2d_hash(q2t, m2, interpret)  # [col, row]
     q3 = jnp.swapaxes(q3t.reshape(k, k, SHARE_SIZE), 0, 1)
     eds = jnp.concatenate([
         jnp.concatenate([shares, q1], axis=1),
@@ -285,29 +233,19 @@ def _roots_of_fused(shares: jnp.ndarray, m2: jnp.ndarray,
 
 
 def _roots_of(shares: jnp.ndarray, m2: jnp.ndarray,
-              fused: bool | None = None, xor: bool | None = None):
+              fused: bool | None = None):
     """Shared core: (k,k,512) -> (eds, row_roots, col_roots).
 
     fused=None resolves via _fused_active (Pallas kernels on an
-    accelerator backend, XLA spelling otherwise); xor=None via
-    _xor_active (measured-table contraction choice, ADR-024); True/False
-    pin a spelling for A/B benching. Byte-identical any way (pinned by
-    tests/test_fused_roots.py, tests/test_xor_schedule.py)."""
+    accelerator backend, XLA spelling otherwise); True/False pin a
+    spelling (the row-sharded mesh path, A/B benching). Byte-identical
+    either way (pinned by tests/test_fused_roots.py)."""
     k = shares.shape[0]
     if fused is None:
         fused = _fused_active(k)
-    if xor is None:
-        xor = _xor_active(k)
     if fused:
-        return _roots_of_fused(shares, m2, xor=xor)
-    if xor:
-        from celestia_tpu.ops import xor_schedule
-
-        eds = xor_schedule.extend_square_xor(
-            shares, xor_schedule.compile_schedule(k)
-        )
-    else:
-        eds = rs_tpu.extend_square(shares, m2)
+        return _roots_of_fused(shares, m2)
+    eds = rs_tpu.extend_square(shares, m2)
     leaf_ns = _leaf_namespaces(shares[..., :NAMESPACE_SIZE], k)
     row_roots, col_roots = nmt_roots_of_eds(eds, leaf_ns)
     return eds, row_roots, col_roots
@@ -629,8 +567,7 @@ def eds_row_levels_device(eds) -> list[np.ndarray]:
         return [np.asarray(lv) for lv in levels]
 
 
-def fused_roots_reference(shares: np.ndarray, tile: int | None = None,
-                          xor: bool = False):
+def fused_roots_reference(shares: np.ndarray, tile: int | None = None):
     """Eager CPU spelling of the FUSED pipeline for parity tests:
     (k,k,512) -> numpy (eds, row_roots, col_roots), running
     rs_pallas's *_reference tile math (the kernels' exact bodies,
@@ -638,18 +575,8 @@ def fused_roots_reference(shares: np.ndarray, tile: int | None = None,
     interpret-mode jit is unusable for the unrolled SHA graph on CPU)
     plus the same digest-grid NMT reduce the device program runs.
     `tile` (rs_pallas reference tile override) trades eager dispatch
-    count for op width — byte-identical output either way. xor=True
-    runs the XOR-schedule contraction's reference spelling instead of
-    the dense one (ADR-024), mirroring _roots_of_fused's switch."""
+    count for op width — byte-identical output either way."""
     from celestia_tpu.ops import rs_pallas
-
-    if xor:
-        from celestia_tpu.ops import xor_schedule
-
-        def _enc_ref(x, _m2, t):
-            return xor_schedule.encode2d_xor_hash_reference(x, t)
-    else:
-        _enc_ref = rs_pallas.encode2d_hash_reference
 
     k = int(shares.shape[0])
     n = k * SHARE_SIZE
@@ -658,13 +585,13 @@ def fused_roots_reference(shares: np.ndarray, tile: int | None = None,
     q0_ns = np.asarray(shares)[..., :NAMESPACE_SIZE]
     ns_pad = np.asarray(rs_pallas.pad_namespaces(jnp.asarray(q0_ns)))
     d0 = rs_pallas.leaf_digests2d_reference(x0, ns_pad, tile)
-    q2f, d2 = _enc_ref(x0, m2, tile)
+    q2f, d2 = rs_pallas.encode2d_hash_reference(x0, m2, tile)
     q2 = q2f.reshape(k, k, SHARE_SIZE)
     x0t = np.swapaxes(shares, 0, 1).reshape(k, n)
-    q1t, d1t = _enc_ref(x0t, m2, tile)
+    q1t, d1t = rs_pallas.encode2d_hash_reference(x0t, m2, tile)
     q1 = np.swapaxes(q1t.reshape(k, k, SHARE_SIZE), 0, 1)
     q2t = np.swapaxes(q2, 0, 1).reshape(k, n)
-    q3t, d3t = _enc_ref(q2t, m2, tile)
+    q3t, d3t = rs_pallas.encode2d_hash_reference(q2t, m2, tile)
     q3 = np.swapaxes(q3t.reshape(k, k, SHARE_SIZE), 0, 1)
     eds = np.concatenate([
         np.concatenate([np.asarray(shares), q1], axis=1),
@@ -904,13 +831,13 @@ def extend_and_root_batched(shares: jnp.ndarray, m2: jnp.ndarray,
 
 
 def _rows_cols_only(shares: jnp.ndarray, m2: jnp.ndarray,
-                    fused: bool | None = None, xor: bool | None = None):
+                    fused: bool | None = None):
     """The ONE roots-only core: (k,k,512) -> (row_roots, col_roots)
     with no EDS in the outputs — the EDS stays an XLA intermediate.
     Every roots-only spelling (single, batched, their jit caches)
     derives from this function so root computation cannot diverge
     between the replay verifier and the proposer path."""
-    _eds, rows, cols = _roots_of(shares, m2, fused=fused, xor=xor)
+    _eds, rows, cols = _roots_of(shares, m2, fused=fused)
     return rows, cols
 
 
@@ -983,16 +910,14 @@ def _jitted_chunk_roots(k: int, chunk: int):
 
 @functools.lru_cache(maxsize=16)
 @devledger.instrument_builder("extend.roots_noeds")
-def _jitted_roots_noeds(k: int, fused: bool | None = None,
-                        xor: bool | None = None):
-    """fused=None / xor=None (the defaults every production caller
-    uses) freeze the _fused_active / _xor_active decisions into this
-    cache entry at first trace; True/False build explicitly-pinned
-    spellings for A/B benching (bench.py --fused-kernels,
-    --xor-schedule)."""
+def _jitted_roots_noeds(k: int, fused: bool | None = None):
+    """fused=None (the default every production caller uses) freezes
+    the _fused_active decision into this cache entry at first trace;
+    True/False build explicitly-pinned spellings for A/B benching
+    (bench.py --fused-kernels)."""
     m2 = jnp.asarray(rs_tpu.encode_bit_matrix(k))
     return jax.jit(
-        lambda shares: _rows_cols_only(shares, m2, fused=fused, xor=xor)
+        lambda shares: _rows_cols_only(shares, m2, fused=fused)
     )
 
 

@@ -86,90 +86,48 @@ def _shard_map(f, mesh, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
-def _contraction_ops(k: int, sp: int, m2, xor: bool):
-    """The two contraction spellings a row-sharded program needs —
-    local row extension and the per-device column-contraction partial —
-    in the dense bit-matmul or XOR-schedule form (ADR-024).
+def _contraction_ops(k: int, sp: int, m2):
+    """The two contraction pieces a row-sharded program needs — local
+    row extension and the per-device column-contraction partial.
 
-    Returns (encode_rows, q2_partial, operands, specs): `q2_partial`
-    maps (bits (k, 8*rows_per, B), *extras) -> (8k, k, B) int8 partial
-    parities ALREADY reduced mod 2, ready for the int8 psum over 'sp'
-    (XOR partials combine under exactly the same mod-2 homomorphism as
-    the dense integer counts). For the XOR spelling, the per-shard
-    column-block schedules cannot be trace-time constants — shard_map
-    traces ONE program for every device — so their index arrays ride as
-    'sp'-sharded operands (`operands`, with `specs` their in_specs) and
-    reach q2_partial as the extras."""
+    Returns (encode_rows, q2_partial): `q2_partial` maps
+    bits (k, 8*rows_per, B) -> (8k, k, B) int8 partial parities ALREADY
+    reduced mod 2, ready for the int8 psum over 'sp'."""
     rows_per = k // sp
-    if not xor:
-
-        def encode_rows(block):
-            return rs_tpu.rs_encode_rows(block, m2)
-
-        def q2_partial(bits):
-            idx = jax.lax.axis_index("sp")
-            # rows of m2 block-select: contraction index q = 8*row +
-            # bit, where row is the GLOBAL row index of this block
-            m2_block = jax.lax.dynamic_slice_in_dim(
-                m2, idx * 8 * rows_per, 8 * rows_per, axis=1
-            ).astype(jnp.int8)
-            partial = jax.lax.dot_general(
-                m2_block, bits,
-                dimension_numbers=(((1,), (bits.ndim - 2,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )  # (8k, k_cols, B)
-            # mod-2 BEFORE the collective: (Σ partial) & 1 ==
-            # (Σ (partial & 1)) & 1, so the psum ships int8 parities —
-            # 4x less interconnect volume than the int32 counts.
-            return (partial & 1).astype(jnp.int8)
-
-        return encode_rows, q2_partial, (), ()
-
-    from celestia_tpu.ops import xor_schedule
-
-    sched = xor_schedule.compile_schedule(k)
-    tpl, fa, fb, ri = xor_schedule.sharded_schedule_arrays(k, sp)
 
     def encode_rows(block):
-        # row extension contracts over the row's OWN bit planes (all
-        # local), so the full-matrix schedule applies with its
-        # trace-time constant indices
-        return xor_schedule.rs_encode_rows_xor(block, sched)
+        return rs_tpu.rs_encode_rows(block, m2)
 
-    def q2_partial(bits, fa_l, fb_l, ri_l):
-        planes = jnp.moveaxis(bits, -2, 0)  # (8*rows_per, k_cols, B)
-        flat = planes.reshape(planes.shape[0], -1).astype(jnp.int32)
-        part = xor_schedule.apply_planes(
-            flat, tpl, flat_a=fa_l[0], flat_b=fb_l[0], row_idx=ri_l[0]
-        )  # (8k, k_cols*B) 0/1 — this shard's column-block XOR
-        return part.reshape(8 * k, *planes.shape[1:]).astype(jnp.int8)
+    def q2_partial(bits):
+        idx = jax.lax.axis_index("sp")
+        # rows of m2 block-select: contraction index q = 8*row + bit,
+        # where row is the GLOBAL row index of this block
+        m2_block = jax.lax.dynamic_slice_in_dim(
+            m2, idx * 8 * rows_per, 8 * rows_per, axis=1
+        ).astype(jnp.int8)
+        partial = jax.lax.dot_general(
+            m2_block, bits,
+            dimension_numbers=(((1,), (bits.ndim - 2,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )  # (8k, k_cols, B)
+        # mod-2 BEFORE the collective: (Σ partial) & 1 ==
+        # (Σ (partial & 1)) & 1, so the psum ships int8 parities —
+        # 4x less interconnect volume than the int32 counts.
+        return (partial & 1).astype(jnp.int8)
 
-    operands = (jnp.asarray(fa), jnp.asarray(fb), jnp.asarray(ri))
-    specs = (P("sp", None), P("sp", None), P("sp", None, None))
-    return encode_rows, q2_partial, operands, specs
+    return encode_rows, q2_partial
 
 
-def extend_and_root_rowsharded(mesh: Mesh, k: int, xor: bool | None = None):
+def extend_and_root_rowsharded(mesh: Mesh, k: int):
     """One square, rows sharded over the 'sp' mesh axis; explicit psum /
-    all_gather collectives. Returns a jitted fn of (k, k, 512) uint8.
-
-    xor=None resolves the contraction spelling via extend_tpu._xor_active
-    at build time (the mesh builders rebuild on set_active_mesh, so the
-    decision freezes per cache entry like the single-device jits)."""
-    if xor is None:
-        from celestia_tpu.ops import extend_tpu
-
-        xor = extend_tpu._xor_active(k)
-
+    all_gather collectives. Returns a jitted fn of (k, k, 512) uint8."""
     m2 = jnp.asarray(rs_tpu.encode_bit_matrix(k))
     sp = mesh.shape["sp"]
     if k % sp:
         raise ValueError(f"square size {k} not divisible by sp={sp}")
-    encode_rows, q2_partial, xor_operands, xor_specs = _contraction_ops(
-        k, sp, m2, xor
-    )
+    encode_rows, q2_partial = _contraction_ops(k, sp, m2)
 
-    def local_fn(shares_block, *xo):  # (k/sp, k, 512) local rows
+    def local_fn(shares_block):  # (k/sp, k, 512) local rows
         # Q1: row extension is local to the row shard.
         q1 = encode_rows(shares_block)
 
@@ -179,7 +137,7 @@ def extend_and_root_rowsharded(mesh: Mesh, k: int, xor: bool | None = None):
         bits = rs_tpu.unpack_bits(cols_local)  # (k, 8*k/sp, B)
         idx = jax.lax.axis_index("sp")
         rows_per = k // sp
-        total = jax.lax.psum(q2_partial(bits, *xo), "sp")
+        total = jax.lax.psum(q2_partial(bits), "sp")
         q2_full = rs_tpu.pack_bits(jnp.moveaxis(total & 1, 0, -2))  # (k, k, B) cols-major
         q2 = jnp.swapaxes(q2_full, 0, 1)  # (k rows, k cols, 512), replicated
 
@@ -238,14 +196,12 @@ def extend_and_root_rowsharded(mesh: Mesh, k: int, xor: bool | None = None):
     sharded = _shard_map(
         local_fn,
         mesh=mesh,
-        in_specs=(P("sp", None, None), *xor_specs),
+        in_specs=(P("sp", None, None),),
         out_specs=(P("sp", None, None), P(), P(), P()),
     )
 
     def reassemble(shares):
-        eds_interleaved, row_roots, col_roots, dah = sharded(
-            shares, *xor_operands
-        )
+        eds_interleaved, row_roots, col_roots, dah = sharded(shares)
         # out rows are [dev0 top | dev0 bottom | dev1 top | ...]: restore
         # global order [all top rows, all bottom rows].
         rows_per = k // sp
@@ -257,8 +213,7 @@ def extend_and_root_rowsharded(mesh: Mesh, k: int, xor: bool | None = None):
     return jax.jit(reassemble)
 
 
-def extend_root_levels_rowsharded(mesh: Mesh, k: int,
-                                  xor: bool | None = None):
+def extend_root_levels_rowsharded(mesh: Mesh, k: int):
     """The block-pipeline hot path: extend + axis roots + EVERY row-tree
     level in ONE sharded program (node/pipeline.py's compute leg). The
     separate levels spelling re-hashes all (2k)² leaf digests the extend
@@ -268,8 +223,6 @@ def extend_root_levels_rowsharded(mesh: Mesh, k: int,
     two. Outputs are byte-identical to extend_and_root_rowsharded
     followed by eds_row_levels_rowsharded. Returns a jitted fn of
     (k, k, 512) uint8 -> (eds, row_roots, col_roots, dah, levels_tuple).
-
-    xor picks the contraction spelling (see extend_and_root_rowsharded).
     """
     from celestia_tpu.appconsts import NAMESPACE_SIZE
     from celestia_tpu.ops.extend_tpu import (
@@ -280,28 +233,21 @@ def extend_root_levels_rowsharded(mesh: Mesh, k: int,
         nmt_reduce_levels,
     )
 
-    if xor is None:
-        from celestia_tpu.ops import extend_tpu
-
-        xor = extend_tpu._xor_active(k)
-
     m2 = jnp.asarray(rs_tpu.encode_bit_matrix(k))
     sp = mesh.shape["sp"]
     if k % sp:
         raise ValueError(f"square size {k} not divisible by sp={sp}")
     rows_per = k // sp
     n_levels = (2 * k).bit_length()
-    encode_rows, q2_partial, xor_operands, xor_specs = _contraction_ops(
-        k, sp, m2, xor
-    )
+    encode_rows, q2_partial = _contraction_ops(k, sp, m2)
 
-    def local_fn(shares_block, *xo):  # (k/sp, k, 512) local rows
+    def local_fn(shares_block):  # (k/sp, k, 512) local rows
         q1 = encode_rows(shares_block)
         cols_local = jnp.swapaxes(shares_block, 0, 1)
         bits = rs_tpu.unpack_bits(cols_local)
         idx = jax.lax.axis_index("sp")
         # int8 parity psum, same mod-2 homomorphism as the unfused spelling
-        total = jax.lax.psum(q2_partial(bits, *xo), "sp")
+        total = jax.lax.psum(q2_partial(bits), "sp")
         q2_full = rs_tpu.pack_bits(jnp.moveaxis(total & 1, 0, -2))
         q2 = jnp.swapaxes(q2_full, 0, 1)
         q2_local = jax.lax.dynamic_slice_in_dim(q2, idx * rows_per, rows_per, axis=0)
@@ -347,15 +293,13 @@ def extend_root_levels_rowsharded(mesh: Mesh, k: int,
     sharded = _shard_map(
         local_fn,
         mesh=mesh,
-        in_specs=(P("sp", None, None), *xor_specs),
+        in_specs=(P("sp", None, None),),
         out_specs=(P("sp", None, None), P(), P(), P(),
                    tuple(P("sp", None, None) for _ in range(n_levels))),
     )
 
     def reassemble(shares):
-        eds_interleaved, row_roots, col_roots, dah, levels = sharded(
-            shares, *xor_operands
-        )
+        eds_interleaved, row_roots, col_roots, dah, levels = sharded(shares)
 
         # shard-order rows are [dev0 top | dev0 bottom | dev1 top | ...]:
         # restore global order [all top rows, all bottom rows] for the

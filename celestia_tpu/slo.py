@@ -50,12 +50,6 @@ log = logger("slo")
 # this node's lifetime is a session, not a quarter (specs/slo.md).
 DEFAULT_WINDOWS = ((300.0, 60.0, 14.4), (3600.0, 300.0, 6.0))
 
-# a crossover table (app/calibration.py) older than this is stale: the
-# hardware it measured may no longer exist. measured_at == 0
-# means "no timestamp recorded" (hand-built tables) and never expires.
-CROSSOVER_MAX_AGE_S = 7 * 24 * 3600.0
-
-
 @dataclasses.dataclass
 class Objective:
     """One declarative objective. Exactly the fields its kind reads."""
@@ -411,7 +405,7 @@ def engine_for(node) -> SloEngine:
 # ---------------------------------------------------------------------- #
 # readiness: serving-fit, distinct from SLO health. /readyz answers
 # "should a load balancer send this node DAS traffic NOW" — conditions
-# are structural (backend, calibration, arena, data), not statistical.
+# are structural (backend, arena, data), not statistical.
 
 
 def readiness(node) -> tuple[bool, list[dict]]:
@@ -448,17 +442,6 @@ def readiness(node) -> tuple[bool, list[dict]]:
         check("backend_resolved", True, f"live={live}")
     except Exception as e:  # noqa: BLE001 — unresolvable backend = unfit
         check("backend_resolved", False, str(e))
-
-    table = app.crossover
-    if table is None:
-        # no table is a legitimate configuration (static-threshold
-        # fallback, ADR-012) — only a STALE table is unfit, because
-        # 'auto' would then route on measurements of dead hardware
-        check("crossover_fresh", True, "no table (static fallback)")
-    else:
-        age = time.time() - table.measured_at if table.measured_at else 0.0
-        check("crossover_fresh", age <= CROSSOVER_MAX_AGE_S,
-              f"age_s={age:.0f}")
 
     pool = app.blob_pool
     if pool is None:

@@ -92,7 +92,6 @@ class _StubApp:
         self._active_backend: str | None = None
         self._tpu_strikes = 0
         self._tpu_disabled = False
-        self.crossover = None
         self.blob_pool = None
         self.arena_stats = {"assembled": 0, "fallback": 0}
         # SDC defense surface (ADR-015): /status + /readyz quarantine

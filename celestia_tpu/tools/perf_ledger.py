@@ -65,29 +65,11 @@ TRACKED: dict[str, list[tuple[str | None, str]]] = {
     "node_path_k128_eds_fetch_ms": [("8_node_path_k128",
                                      "tpu_wall_with_eds_fetch_ms")],
     # fused extend+hash roots-only pipeline at the governance-default
-    # square (ADR-019, bench.py --fused-kernels): the wall that decides
-    # the k=64 crossover. A regression here silently re-opens the gap
-    # the fused kernel closed, so the step-change gates once it has
-    # history.
+    # square (ADR-019, bench.py --fused-kernels). A regression here
+    # silently re-opens the gap the fused kernel closed, so the
+    # step-change gates once it has history.
     "fused_ms_per_square_k64": [("12_fused_kernels_k64",
                                  "fused_ms_per_square")],
-    # XOR-schedule contraction at the governance-default square
-    # (ADR-024, bench.py --xor-schedule): ms/square of the sparse
-    # CSE-shared schedule through the roots-only core. Rides the same
-    # lower-is-better double gate as the walls above once it has
-    # min_history points — a regression here means the schedule
-    # compiler (or its XLA lowering) lost the ground the A/B won.
-    "xor_schedule_ms_per_square_k64": [("13_xor_schedule_k64",
-                                        "xor_ms_per_square")],
-    # the recalibrated crossover point: the TPU side of the k=64 rung.
-    # History accrues from the measured fused config like the series
-    # above, but the loader appends the COMMITTED table's rung
-    # (config/crossover.json entries["64"]["tpu"]) as the final point —
-    # committing a recalibration whose k=64 TPU wall regressed against
-    # the measured trajectory fails the gate, tying `auto` routing to
-    # real numbers.
-    "crossover_k64_tpu_ms": [("12_fused_kernels_k64",
-                              "fused_ms_per_square")],
     # serving: per-accepted-sample wall of the batched das-storm phase
     # (`make storm-bench`). Not extracted from BENCH rounds — the
     # loader folds it in from storm_ledger.json, hence no paths here.
@@ -257,22 +239,6 @@ def load_ledger(root: str) -> dict[str, list[tuple[str, float]]]:
             v = _extract(metric, parsed)
             if v is not None:
                 ledger[metric].append((label, v))
-    # committed crossover table (ADR-019): its k=64 TPU rung becomes
-    # the FINAL point of the crossover series, so the gate judges the
-    # committed routing numbers against the measured fused-config
-    # history
-    xover_path = os.path.join(root, "config", "crossover.json")
-    if os.path.exists(xover_path):
-        try:
-            with open(xover_path) as f:
-                xover = json.load(f)
-        except (OSError, ValueError):
-            xover = None
-        if isinstance(xover, dict):
-            v = (xover.get("entries", {}).get("64") or {}).get("tpu")
-            if isinstance(v, (int, float)):
-                ledger["crossover_k64_tpu_ms"].append(
-                    ("config/crossover.json", float(v)))
     # storm ledger (`bench.py --das-storm --ledger`): its own capped
     # run history, already oldest→newest — each run is one point of the
     # storm_ms_per_accepted_sample series

@@ -254,6 +254,21 @@ class TestConfig:
         cfg = load_config(tmp_path)
         assert cfg.app.min_gas_price == pytest.approx(0.75)
 
+    def test_file_layer_ignores_retired_keys(self, tmp_path):
+        """An app.toml written by an older release may carry fields this
+        one no longer has: they are ignored, and the node still boots."""
+        from celestia_tpu.config import load_config, write_default_configs
+
+        write_default_configs(tmp_path)
+        app_toml = tmp_path / "config" / "app.toml"
+        text = app_toml.read_text()
+        assert "[app]\n" in text
+        app_toml.write_text(text.replace(
+            "[app]\n", "[app]\nretired_switch = true\n", 1))
+        cfg = load_config(tmp_path)
+        assert not hasattr(cfg.app, "retired_switch")
+        assert cfg.app.extend_backend == "auto"
+
     def test_env_layer_overrides_file(self, tmp_path, monkeypatch):
         from celestia_tpu.config import load_config, write_default_configs
 

@@ -63,6 +63,41 @@ class TestRsBitMatmul:
         assert np.array_equal(ref, got)
 
 
+POW2_KS = [2, 4, 8, 16, 32, 64, 128]
+
+
+def _assert_matrix_matches_leopard(k: int) -> None:
+    """The expanded (8k,8k) GF(2) matrix must spell exactly the Leopard
+    encode: parity bytes via unpack -> m2-contraction -> pack equal
+    gf256.leopard_encode on random shards."""
+    rng = np.random.default_rng(1000 + k)
+    data = rng.integers(0, 256, size=(k, 48), dtype=np.uint8)
+    ref = gf256.leopard_encode(data)
+    # pure-numpy spelling of the bit contraction (LSB-first planes,
+    # contraction index q = 8*shard + bit — the rs_tpu layout contract)
+    bits = ((data[:, None, :] >> np.arange(8)[None, :, None]) & 1).reshape(
+        8 * k, -1
+    )
+    m2 = rs_tpu.encode_bit_matrix(k)
+    pbits = ((m2.astype(np.int64) @ bits) & 1).reshape(k, 8, -1)
+    got = (pbits << np.arange(8)[None, :, None]).sum(axis=1).astype(np.uint8)
+    assert np.array_equal(ref, got)
+
+
+class TestEncodeMatrixVsLeopard:
+    """Every committed power-of-two k (2..128); k >= 32 rides the slow
+    tier."""
+
+    @pytest.mark.parametrize("k", [k for k in POW2_KS if k < 32])
+    def test_matrix_matches_leopard(self, k):
+        _assert_matrix_matches_leopard(k)
+
+    @pytest.mark.slow
+    @pytest.mark.parametrize("k", [k for k in POW2_KS if k >= 32])
+    def test_matrix_matches_leopard_large(self, k):
+        _assert_matrix_matches_leopard(k)
+
+
 class TestExtendAndRoot:
     @pytest.mark.parametrize("k", [1, 2, 4])
     def test_byte_parity_vs_host(self, k):

@@ -106,12 +106,35 @@ class TestFusedDahParity:
         assert not rs_pallas.supported(8, 8 * 512)
 
     def test_fused_inactive_on_cpu_backend(self):
-        # auto resolution keeps the XLA spelling on the CPU backend —
-        # Mosaic kernels can't lower there (env override still wins)
+        # the CPU backend keeps the XLA spelling — Mosaic kernels can't
+        # lower there
         import jax
 
         if jax.default_backend() == "cpu":
             assert not extend_tpu._fused_active(64)
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    @pytest.mark.parametrize("k", [4, 8, 16, 64, 128])
+    def test_fused_active_rule(self, monkeypatch, backend, k):
+        """Fused exactly when the backend is an accelerator AND the
+        kernels tile k (the `_MIN_K` floor sits between 8 and 16) —
+        decided from what the process sees, with no environment read."""
+        import os
+
+        import jax
+
+        class _NoReads(dict):
+            def __getitem__(self, key):
+                raise AssertionError(f"environment read: {key}")
+
+            get = __contains__ = __getitem__
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(os, "environ", _NoReads(os.environ))
+        want = backend == "tpu" and k >= rs_pallas._MIN_K
+        assert want == (backend == "tpu"
+                        and rs_pallas.fused_supported(k, k * 512))
+        assert extend_tpu._fused_active(k) is want
 
 
 class TestDeviceProverSeeding:

@@ -47,7 +47,7 @@ def test_respawn_env_is_scrubbed():
     poisoned = {
         "JAX_PLATFORMS": "tpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-        "CELESTIA_FUSED_KERNELS": "0",
+        "CELESTIA_APP_MIN_GAS_PRICE": "0.5",
     }
     old = {k: os.environ.get(k) for k in
            (*poisoned, "JAX_COMPILATION_CACHE_DIR")}
@@ -58,7 +58,7 @@ def test_respawn_env_is_scrubbed():
         assert env["JAX_PLATFORMS"] == "cpu"
         assert env["XLA_FLAGS"] == "--xla_force_host_platform_device_count=8"
         assert env[g._CHILD_SENTINEL] == "1"
-        assert env["CELESTIA_FUSED_KERNELS"] == "0"
+        assert env["CELESTIA_APP_MIN_GAS_PRICE"] == "0.5"
         assert "JAX_COMPILATION_CACHE_DIR" not in env
         assert set(env) - set(os.environ) == {g._CHILD_SENTINEL}
     finally:

@@ -313,31 +313,53 @@ class TestStateSync:
 
 class TestExtendBackend:
     """Backend selection for the ExtendBlock hot path (config flag +
-    crossover auto rule) — the operator-facing TPU wiring."""
+    auto rule) — the operator-facing TPU wiring."""
+
+    @staticmethod
+    def _app(monkeypatch, backend, accel, native_ok):
+        import celestia_tpu.app.app as app_mod
+        from celestia_tpu import native
+
+        monkeypatch.setattr(app_mod, "accelerator_available", lambda: accel)
+        monkeypatch.setattr(native, "available", lambda: native_ok)
+        return App(extend_backend=backend)
 
     def test_invalid_backend_rejected(self):
         with pytest.raises(ValueError, match="unknown extend backend"):
             App(extend_backend="cuda")
 
-    def test_auto_rules(self, monkeypatch):
-        import celestia_tpu.app.app as app_mod
-        from celestia_tpu import native
+    @pytest.mark.parametrize("native_ok", [True, False],
+                             ids=["native", "no-native"])
+    @pytest.mark.parametrize("accel", [True, False],
+                             ids=["accel", "no-accel"])
+    @pytest.mark.parametrize("k", [1, 2, 4, 8, 16, 32, 64, 128])
+    def test_auto_backend_matches_parent(self, monkeypatch, k, accel,
+                                         native_ok):
+        """auto: the device on an accelerator at every k (below k=16
+        too), else the native runtime, else numpy."""
+        app = self._app(monkeypatch, "auto", accel, native_ok)
+        want = "tpu" if accel else "native" if native_ok else "numpy"
+        assert app.resolve_extend_backend(k) == want
 
-        app = App(extend_backend="auto")
-        # fresh Apps carry the repo-committed default table (ADR-019);
-        # detach it here to pin the STATIC-gate fallback rules
-        app.crossover = None
-        # accelerator present: device above the crossover, native below
-        monkeypatch.setattr(app_mod, "_accel_probe", True)
-        monkeypatch.setattr(native, "available", lambda: True)
-        assert app.resolve_extend_backend(128) == "tpu"
-        assert app.resolve_extend_backend(app_mod.TPU_MIN_SQUARE) == "tpu"
-        assert app.resolve_extend_backend(2) == "native"
-        # no accelerator: native everywhere, numpy as last resort
-        monkeypatch.setattr(app_mod, "_accel_probe", False)
-        assert app.resolve_extend_backend(128) == "native"
-        monkeypatch.setattr(native, "available", lambda: False)
-        assert app.resolve_extend_backend(128) == "numpy"
+    @pytest.mark.parametrize("backend,native_ok,disabled,want", [
+        ("tpu", True, False, "tpu"),
+        ("tpu", False, False, "tpu"),
+        ("native", True, False, "native"),
+        ("native", False, False, "numpy"),
+        ("numpy", True, False, "numpy"),
+        ("tpu", True, True, "native"),
+        ("tpu", False, True, "numpy"),
+        ("auto", True, True, "native"),
+        ("auto", False, True, "numpy"),
+    ])
+    def test_explicit_and_degraded_backend(self, monkeypatch, backend,
+                                           native_ok, disabled, want):
+        """Explicit backends are honoured; native without its runtime
+        falls to numpy; a struck-out device falls to native, then
+        numpy — with an accelerator present throughout."""
+        app = self._app(monkeypatch, backend, True, native_ok)
+        app._tpu_disabled = disabled
+        assert app.resolve_extend_backend(64) == want
 
     def test_accelerator_init_failure_is_logged(self, monkeypatch):
         """A device that fails to initialize reads as "no accelerator",

@@ -8,15 +8,12 @@ over the REAL node/rpc.py handler serving the crypto-free RpcChaosNode
 facade, so the suite runs in stripped environments."""
 
 import json
-import time
-import types
 import urllib.error
 import urllib.request
 
 import pytest
 
 from celestia_tpu.slo import (
-    CROSSOVER_MAX_AGE_S,
     Objective,
     SloEngine,
     default_objectives,
@@ -201,18 +198,6 @@ class TestReadiness:
         detail = next(c["detail"] for c in checks
                       if c["name"] == "not_sticky_degraded")
         assert "3 strikes" in detail
-
-    def test_stale_crossover_table_is_unfit(self):
-        node = RpcChaosNode(heights=1)
-        node.app.crossover = types.SimpleNamespace(
-            measured_at=time.time() - CROSSOVER_MAX_AGE_S - 60.0
-        )
-        ready, checks = readiness(node)
-        assert not ready and not check_map(checks)["crossover_fresh"]
-        # a table with no timestamp (hand-built) never expires
-        node.app.crossover = types.SimpleNamespace(measured_at=0)
-        ready, _checks = readiness(node)
-        assert ready
 
     def test_exhausted_arena_is_unfit(self):
         node = RpcChaosNode(heights=1)

@@ -1,6 +1,6 @@
-"""Transfer-path tests (specs/transfers.md, ADR-012).
+"""Transfer-path tests (specs/transfers.md).
 
-Pins the three transfer disciplines introduced with the sliced-serving
+Pins the two transfer disciplines introduced with the sliced-serving
 PR:
 
 1. sliced device→host EDS reads (`da.ExtendedDataSquare.row/col/share`
@@ -11,17 +11,12 @@ PR:
 2. chunked overlapped bulk transfers (`ops.transfers.device_put_chunked`
    / `device_get_chunked`) round-trip byte-identically for odd shapes
    and chunk counts, with exact byte telemetry, and the chunked repair
-   path stays byte-identical under an armed fault injector;
-3. the calibrated crossover (`app.calibration.CrossoverTable`) picks the
-   measured winner per k, extrapolates by nearest log2 rung, survives a
-   save/load round trip, and `auto` backend resolution follows it.
+   path stays byte-identical under an armed fault injector.
 
 Slicing/transfer parity is coding-independent, so most tests use raw
 random squares (cheap at k=128); only the root-parity test needs a valid
 namespace-ordered square.
 """
-
-import json
 
 import numpy as np
 import pytest
@@ -220,139 +215,6 @@ class TestChunkedRepairUnderFaults:
         src = np.where(present[..., None], eds.data, 0)
         got = repair_tpu.repair_tpu(jax.device_put(src), present)
         assert np.array_equal(got, eds.data)
-
-
-class TestCrossoverTable:
-    """app/calibration.py — importable without the app package (no
-    cryptography dependency at module level)."""
-
-    def _table(self):
-        from celestia_tpu.app.calibration import CrossoverTable
-
-        return CrossoverTable(
-            entries={
-                16: {"tpu": 250.0, "native": 3.0},
-                64: {"tpu": 120.0, "native": 55.0},
-                128: {"tpu": 90.0, "native": 400.0},
-            },
-            measured_at=1700000000.0,
-        )
-
-    def test_winner_measured_rungs(self):
-        t = self._table()
-        assert t.winner(16) == "native"
-        assert t.winner(64) == "native"
-        assert t.winner(128) == "tpu"
-
-    def test_winner_nearest_log2_rung(self):
-        t = self._table()
-        # log2(32)=5 is equidistant from rungs 16 (4) and 64 (6):
-        # ties go to the smaller rung
-        assert t.winner(32) == t.winner(16) == "native"
-        assert t.winner(100) == t.winner(128) == "tpu"  # log2 ~6.64
-        assert t.winner(4) == t.winner(16)  # below the ladder
-        assert t.winner(512) == t.winner(128)  # above the ladder
-
-    def test_empty_table(self):
-        from celestia_tpu.app.calibration import CrossoverTable
-
-        assert CrossoverTable(entries={}).winner(64) is None
-
-    def test_save_load_roundtrip(self, tmp_path):
-        from celestia_tpu.app.calibration import CrossoverTable
-
-        path = tmp_path / "config" / "crossover.json"
-        t = self._table()
-        t.save(path)
-        loaded = CrossoverTable.load(path)
-        assert loaded is not None
-        assert loaded.entries == t.entries  # int keys restored from JSON
-        assert loaded.measured_at == t.measured_at
-        assert loaded.winner(64) == t.winner(64)
-
-    def test_load_missing_or_corrupt(self, tmp_path):
-        from celestia_tpu.app.calibration import CrossoverTable
-
-        assert CrossoverTable.load(tmp_path / "nope.json") is None
-        bad = tmp_path / "bad.json"
-        bad.write_text("{not json")
-        assert CrossoverTable.load(bad) is None  # node must still boot
-
-    def test_json_shape(self, tmp_path):
-        path = tmp_path / "crossover.json"
-        self._table().save(path)
-        doc = json.loads(path.read_text())
-        assert set(doc["entries"]) == {"16", "64", "128"}
-
-
-class TestAutoResolveFollowsCalibration:
-    """Regression: with a CrossoverTable attached, `auto` at each k
-    resolves to the measured winner (re-checked against live backend
-    availability). Needs the app package (cryptography)."""
-
-    def _app(self, monkeypatch, accel: bool, native_ok: bool):
-        pytest.importorskip("cryptography")
-        from celestia_tpu import native
-        from celestia_tpu.app import app as app_mod
-
-        monkeypatch.setattr(app_mod, "accelerator_available", lambda: accel)
-        monkeypatch.setattr(native, "available", lambda: native_ok)
-        return app_mod.App(extend_backend="auto")
-
-    def _table(self):
-        from celestia_tpu.app.calibration import DEFAULT_KS, CrossoverTable
-
-        # alternate winners across the ladder so the test distinguishes
-        # table-driven from gate-driven resolution
-        entries = {
-            k: (
-                {"tpu": 1.0, "native": 9.0}
-                if i % 2
-                else {"tpu": 9.0, "native": 1.0}
-            )
-            for i, k in enumerate(DEFAULT_KS)
-        }
-        return CrossoverTable(entries=entries), DEFAULT_KS
-
-    def test_auto_matches_winner_each_k(self, monkeypatch):
-        app = self._app(monkeypatch, accel=True, native_ok=True)
-        table, ks = self._table()
-        app.crossover = table
-        for k in ks:
-            assert app.resolve_extend_backend(k) == table.winner(k)
-
-    def test_winner_degrades_without_backend(self, monkeypatch):
-        # table says tpu everywhere, but no accelerator: fall back to the
-        # static gate (native here), never a dead backend
-        pytest.importorskip("cryptography")
-        from celestia_tpu.app.calibration import CrossoverTable
-
-        app = self._app(monkeypatch, accel=False, native_ok=True)
-        app.crossover = CrossoverTable(entries={64: {"tpu": 1.0}})
-        assert app.resolve_extend_backend(64) == "native"
-
-    def test_uncalibrated_keeps_static_gate(self, monkeypatch):
-        app = self._app(monkeypatch, accel=True, native_ok=True)
-        from celestia_tpu.app import app as app_mod
-        # a fresh App attaches the repo-committed default table
-        # (ADR-019); uncalibrated means detaching it explicitly
-        app.crossover = None
-        assert app.resolve_extend_backend(app_mod.TPU_MIN_SQUARE) == "tpu"
-        assert (
-            app.resolve_extend_backend(app_mod.TPU_MIN_SQUARE // 2) == "native"
-        )
-
-    def test_fresh_app_carries_committed_default(self, monkeypatch):
-        # ADR-019: `auto` routes on measured numbers out of the box —
-        # the committed config/crossover.json picks TPU at the
-        # governance-default k=64, and availability re-checking keeps
-        # the same table safe on hosts without the hardware
-        app = self._app(monkeypatch, accel=True, native_ok=True)
-        assert app.crossover is not None
-        assert app.crossover.winner(64) == "tpu"
-        assert app.resolve_extend_backend(64) == "tpu"
-        cpu_app = self._app(monkeypatch, accel=False, native_ok=False)
-        assert cpu_app.resolve_extend_backend(64) == "numpy"
 
 
 class TestArenaSemispace:
