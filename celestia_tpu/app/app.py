@@ -567,8 +567,8 @@ class App:
         blob_lens: list[int] = []
         resident = total = 0
         # blob_layout is export order: the cursor only advances, so
-        # starts are ASCENDING — the device-side searchsorted
-        # derivation (_derive_cells) depends on that
+        # starts are ASCENDING — the device-side derivation
+        # (_derive_cells) depends on that
         for start, blob in builder.blob_layout():
             total += len(blob.data)
             ns_obj = blob.namespace()

@@ -15,10 +15,13 @@ needs at most 14 programs, built once each, for any mix of blob sizes
 (one per 4 KB slot size would be thousands, rebuilt as sizes change). The
 program writes exactly the slot's bytes. At proposal time
 the device assembles the square itself (ops/extend_tpu.assembled_roots):
-only the compact tx/PFB/padding shares, the 34-byte share prefixes, and
-int32 offset vectors cross the interconnect — tens of KB instead of MB —
-and the extend+NMT pipeline runs fused on the assembled square without
-it ever existing host-side.
+only the compact tx/PFB/padding shares, the per-blob namespaces and
+lengths, and int32 offset vectors cross the interconnect — tens of KB
+instead of MB. The program reads the arena as 512-byte rows: each blob
+cell gathers the two whole rows its payload lies in and shifts them
+into place, so the arena needs no alignment beyond a byte. The
+extend+NMT pipeline runs fused on the assembled square without it ever
+existing host-side.
 
 ref: the reference keeps mempool blobs host-side and re-marshals them
 into the square per proposal (pkg/square/builder.go); on a TPU node the

@@ -627,44 +627,83 @@ def _jitted_digest_grid_roots():
 # ever existing host-side.
 
 
-def _derive_cells(blob_meta, host_sparse, k: int):
+_PREFIX_MAX = NAMESPACE_SIZE + 1 + 4  # namespace ‖ info ‖ BE length
+
+
+def _derive_cells(blob_meta, host_sparse, ns_len_table, k: int):
     """Expand PER-BLOB metadata into the per-cell vectors ON DEVICE.
 
     blob_meta is (4, B) int32 — [start_cell | n_shares | arena_off |
-    blob_len] with starts ascending (the builder lays blobs out at an
-    increasing cursor) and padding rows start_cell = S, n_shares = 0.
-    host_sparse is (2, Hc) int32 — [cell_pos | host_row] pairs for the
-    cells NOT covered by a resident blob, padding pos = S (dropped).
+    blob_len] with starts strictly ascending (the builder lays blobs
+    out at an increasing cursor) and padding rows start_cell = S,
+    n_shares = 0. host_sparse is (2, Hc) int32 — [cell_pos | host_row]
+    pairs for the cells NOT covered by a resident blob, padding pos = S
+    (dropped). ns_len_table is (B, 33) uint8 — namespace ‖ BE length.
 
-    Deriving here is what shrinks the proposal upload from O(k²)
-    per-cell vectors (~320 KB at k=128) to O(#blobs + #host cells)
-    rows (~1-10 KB): on a high-RTT, low-bandwidth link the metadata
-    transfer WAS the assembled path's wall time."""
+    A cell's blob is the last one that starts at or before it: each
+    blob's number is scattered at its start cell and carried forward by
+    a running maximum (a log-depth scan, no loop).
+
+    Returns (cell_host_row, prefix (S, 34) uint8, prefix_len,
+    data_start, data_len). Deriving here is what shrinks the
+    proposal upload from O(k²) per-cell vectors (~320 KB at k=128) to
+    O(#blobs + #host cells) rows (~1-10 KB): on a high-RTT,
+    low-bandwidth link the metadata transfer WAS the assembled path's
+    wall time."""
     s = k * k
+    b_pad = blob_meta.shape[1]
     s_idx = jnp.arange(s, dtype=jnp.int32)
-    starts = blob_meta[0]
-    b = jnp.clip(
-        jnp.searchsorted(starts, s_idx, side="right").astype(jnp.int32) - 1,
-        0, blob_meta.shape[1] - 1,
-    )
-    j_in = s_idx - starts[b]
-    in_blob = (j_in >= 0) & (j_in < blob_meta[1][b])
-    first = FIRST_SPARSE
-    cont = CONT_SPARSE
-    cell_first = in_blob & (j_in == 0)
-    doff = jnp.where(cell_first, 0, first + (j_in - 1) * cont)
-    data_start = jnp.where(in_blob, blob_meta[2][b] + doff, 0)
-    cap = jnp.where(cell_first, first, cont)
-    data_len = jnp.where(
-        in_blob, jnp.minimum(cap, blob_meta[3][b] - doff), 0
-    )
-    cell_blob = jnp.where(in_blob, b, 0)
+    mark = jnp.zeros((s,), jnp.int32).at[blob_meta[0]].set(
+        jnp.arange(1, b_pad + 1, dtype=jnp.int32), mode="drop")
+    b = jnp.maximum(jax.lax.associative_scan(jnp.maximum, mark) - 1, 0)
+    # one row per blob: its first share's prefix bytes (namespace ‖ info
+    # 0 ‖ BE length), then [start_cell | n_shares | arena_off | blob_len].
+    # A cell reads all of it with ONE whole-row gather: a gather of part
+    # of a row (ns_len_table[b, :29]) lowers on the TPU to a loop of one
+    # dynamic slice per cell.
+    table = jnp.concatenate([
+        ns_len_table[:, :NAMESPACE_SIZE].astype(jnp.int32),
+        jnp.zeros((b_pad, 1), jnp.int32),
+        ns_len_table[:, NAMESPACE_SIZE:].astype(jnp.int32),
+        blob_meta.T,
+    ], axis=1)
+    row = table[b]  # (S, 38)
+    start, n_shares, arena_off, blob_len = (
+        row[:, _PREFIX_MAX + i] for i in range(4))
+    j_in = s_idx - start
+    in_blob = (j_in >= 0) & (j_in < n_shares)
+    first = in_blob & (j_in == 0)
+    doff = jnp.where(first, 0, FIRST_SPARSE + (j_in - 1) * CONT_SPARSE)
+    data_start = jnp.where(in_blob, arena_off + doff, 0)
+    cap = jnp.where(first, FIRST_SPARSE, CONT_SPARSE)
+    data_len = jnp.where(in_blob, jnp.minimum(cap, blob_len - doff), 0)
+    lane = jnp.arange(_PREFIX_MAX, dtype=jnp.int32)
+    prefix = jnp.where(  # share version 0: the info byte is the start bit
+        lane == NAMESPACE_SIZE, first[:, None].astype(jnp.int32),
+        row[:, :_PREFIX_MAX],
+    ).astype(jnp.uint8)
+    prefix_len = jnp.where(first, _PREFIX_MAX, NAMESPACE_SIZE + 1)
     cell_host_row = (
         jnp.full((s,), -1, jnp.int32)
         .at[host_sparse[0]]
         .set(host_sparse[1], mode="drop")
     )
-    return cell_host_row, cell_blob, cell_first, data_start, data_len
+    return cell_host_row, prefix, prefix_len, data_start, data_len
+
+
+def _rotate_left(window, shift):
+    """Rotate each row of a (S, n) uint8 block left by its own byte count
+    (n a power of two): a log-step barrel shift, one static roll and
+    select per bit of the count, so every op is dense and its sizes are
+    fixed at compile time."""
+    n = window.shape[1]
+    shift = shift % n
+    step = 1
+    while step < n:
+        window = jnp.where(((shift & step) != 0)[:, None],
+                           jnp.roll(window, -step, axis=1), window)
+        step *= 2
+    return window
 
 
 def _assemble_square(arena, host_shares, blob_meta, host_sparse,
@@ -683,28 +722,34 @@ def _assemble_square(arena, host_shares, blob_meta, host_sparse,
     blob share assembled in place: namespace ‖ info ‖ [seq len] ‖
     arena[data_start : data_start+data_len] ‖ zeros — exactly the
     sparse splitter's layout (shares/splitters.py write), so the result
-    is byte-identical to the host-built square (pinned by tests)."""
-    j = jnp.arange(SHARE_SIZE, dtype=jnp.int32)  # (512,)
-    cell_host_row, cell_blob, cell_first, data_start, data_len = \
-        _derive_cells(blob_meta, host_sparse, k)
+    is byte-identical to the host-built square (pinned by tests).
 
-    blob_idx = jnp.clip(cell_blob, 0, ns_len_table.shape[0] - 1)
-    ns = ns_len_table[blob_idx, :NAMESPACE_SIZE]  # (S, 29)
-    info = jnp.where(cell_first, 1, 0).astype(jnp.uint8)  # share version 0
-    seq_bytes = ns_len_table[blob_idx, NAMESPACE_SIZE:]  # (S, 4) BE length
-    prefix = jnp.concatenate([ns, info[:, None], seq_bytes], axis=-1)  # (S, 34)
-    prefix_len = jnp.where(cell_first, 34, 30).astype(jnp.int32)
+    The payload moves a share at a time, never a byte at a time: it is
+    at most 482 bytes, so it lies inside arena rows data_start // 512
+    and the next (clamped at the arena's end, where data_len masks it).
+    Each cell gathers those two whole rows and rotates them left by
+    data_start % 512 − prefix_len, which puts the payload's first byte
+    right after the prefix."""
+    cell_host_row, prefix, prefix_len, data_start, data_len = \
+        _derive_cells(blob_meta, host_sparse, ns_len_table, k)
 
-    pref_padded = jnp.pad(prefix, ((0, 0), (0, SHARE_SIZE - prefix.shape[1])))
-    data_pos = j[None, :] - prefix_len[:, None]  # (S, 512)
-    arena_idx = jnp.clip(
-        data_start[:, None] + data_pos, 0, arena.shape[0] - 1
+    pad = -arena.shape[0] % SHARE_SIZE
+    rows = jnp.pad(arena, (0, pad)).reshape(-1, SHARE_SIZE)
+    pair = jnp.minimum(
+        (data_start // SHARE_SIZE)[:, None] + jnp.arange(2, dtype=jnp.int32),
+        rows.shape[0] - 1,
     )
-    arena_vals = arena[arena_idx]  # (S, 512) HBM gather
+    window = rows[pair].reshape(k * k, 2 * SHARE_SIZE)  # whole-row gather
+    data = _rotate_left(
+        window, data_start % SHARE_SIZE - prefix_len
+    )[:, :SHARE_SIZE]
+
+    j = jnp.arange(SHARE_SIZE, dtype=jnp.int32)
     in_prefix = j[None, :] < prefix_len[:, None]
-    in_data = (~in_prefix) & (data_pos < data_len[:, None])
+    in_data = (~in_prefix) & (j[None, :] < (prefix_len + data_len)[:, None])
+    pref_padded = jnp.pad(prefix, ((0, 0), (0, SHARE_SIZE - _PREFIX_MAX)))
     blob_cells = jnp.where(
-        in_prefix, pref_padded, jnp.where(in_data, arena_vals, 0)
+        in_prefix, pref_padded, jnp.where(in_data, data, 0)
     )
 
     hrow = jnp.clip(cell_host_row, 0, host_shares.shape[0] - 1)
@@ -756,9 +801,10 @@ def assembled_roots(
     s = k * k
     starts_arr = np.asarray(blob_start, np.int64)
     if len(starts_arr) > 1 and not np.all(np.diff(starts_arr) > 0):
-        # the device searchsorted derivation silently misattributes
-        # cells if starts are not strictly ascending — fail LOUDLY here
-        # rather than sign a proposal with corrupt roots
+        # the device derivation (_derive_cells' running maximum)
+        # silently misattributes cells if starts are not strictly
+        # ascending — fail LOUDLY here rather than sign a proposal
+        # with corrupt roots
         raise ValueError("blob_start must be strictly ascending")
     with tracing.span("extend.assemble", backend="tpu", k=k,
                       blobs=len(ns_table), host_cells=len(host_pos)):
@@ -795,8 +841,8 @@ def _assembled_roots_traced(arena, host_shares, host_pos, host_row,
             len(ns_table), 4
         )
     nslen_dev = stage(nslen)
-    # padding rows: start = S (past every cell, keeps starts sorted so
-    # searchsorted never lands a real cell there), n_shares = 0
+    # padding rows: start = S (past every cell, so the device scatter
+    # drops them and no real cell lands there), n_shares = 0
     bm = np.zeros((4, b_pad), np.int32)
     bm[0, :] = s
     n_b = len(ns_table)

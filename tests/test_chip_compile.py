@@ -133,6 +133,33 @@ def test_blob_arena_insert_updates_in_place(one_chip, size_class):
     assert mem.temp_size_in_bytes < size_class
 
 
+def test_square_assembly_k128_has_no_loop(one_chip):
+    """The proposal square's assembly from the 64 MB arena, as the
+    chip's compiler lowers it: no loop and no dynamic slice (a gather of
+    part of a row became a per-cell loop there), and no gather of single
+    elements (the per-byte form)."""
+    import functools
+    import re
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from celestia_tpu.ops import extend_tpu
+
+    k = 128
+    args = [_spec(s, d, one_chip) for s, d in (
+        ((64 << 20,), jnp.uint8), ((16, SHARE), jnp.uint8),
+        ((4, 64), jnp.int32), ((2, 1024), jnp.int32), ((64, 33), jnp.uint8))]
+    text = _compile(functools.partial(extend_tpu._assemble_square, k=k),
+                    *args).as_text()
+    assert " while(" not in text
+    assert " dynamic-slice(" not in text
+    sizes = re.findall(r" gather\(.*?slice_sizes=\{([\d,]+)\}", text)
+    assert len(sizes) == 3  # blob table, arena rows, host shares
+    for s in sizes:
+        assert np.prod([int(x) for x in s.split(",")]) > 1, s
+
+
 def test_extend_and_roots_only_default_spelling(one_chip, monkeypatch):
     """The jitted roots program as the chip would trace it: the fused
     decision is taken for a TPU backend, which the test (not the
