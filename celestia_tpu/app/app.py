@@ -1286,12 +1286,15 @@ class App:
     # ------------------------------------------------------------------ #
     # ExtendBlock (post-consensus EDS recompute). ref: app/extend_block.go:14
 
-    def extend_block(self, txs: list[bytes]):
+    def extend_block(self, txs: list[bytes]) -> tuple:
+        """(eds, dah) of the committed block's square. The DAH is the one
+        the extend computed beside the EDS (on the device backend, the
+        same program's roots), so a caller that stores the block never
+        hashes the square again on the host."""
         data_square = square_pkg.construct(
             txs, self.app_version, appconsts.square_size_upper_bound(self.app_version)
         )
-        eds, _dah = self._extend_and_hash(data_square)
-        return eds
+        return self._extend_and_hash(data_square)
 
     # ------------------------------------------------------------------ #
 

@@ -28,6 +28,7 @@ from celestia_tpu.appconsts import (
 )
 from celestia_tpu.ops import gf256
 from celestia_tpu.ops.nmt_host import merkle_root, nmt_root
+from celestia_tpu.telemetry import metrics
 
 PARITY_NS = ns.PARITY_SHARES_NAMESPACE.bytes
 
@@ -201,6 +202,7 @@ class ExtendedDataSquare:
     def row_roots(self) -> list[bytes]:
         # roots consume every cell — materialize once, then host rows
         _ = self.data
+        metrics.incr_counter("host_nmt_roots_total", self.width)
         with tracing.span("extend.nmt.rows", backend="host",
                           width=self.width):
             return [_axis_root(self.row(i), i, self.original_width)
@@ -208,6 +210,7 @@ class ExtendedDataSquare:
 
     def col_roots(self) -> list[bytes]:
         _ = self.data
+        metrics.incr_counter("host_nmt_roots_total", self.width)
         with tracing.span("extend.nmt.cols", backend="host",
                           width=self.width):
             return [_axis_root(self.col(j), j, self.original_width)

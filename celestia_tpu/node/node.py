@@ -255,6 +255,8 @@ class Node:
         self.dispatcher = None
 
     MAX_FRAUD_PROOFS_PER_HEIGHT = 4
+    # heights whose retention-computed DAH stays in the /dah memo
+    _DAH_SEED_HEIGHTS = 64
 
     def boot_extend_backend(self) -> str:
         """Resolve the extend backend at the governance square cap and,
@@ -455,72 +457,130 @@ class Node:
             evidence=list(evidence or []),
             version=build_version,
         )
-        self._store_block(block)
-        # (skip retention across an upgrade boundary: extend_block runs
-        # at the POST-commit version, the square was built at the
-        # pre-commit one — block_eds's versioned reconstruction governs)
-        if self.extend_blocks and build_version == self.app.app_version:
-            # ExtendBlock retention: keep the committed square's EDS
-            # handle (device-resident + lazy on the TPU backend) so the
-            # serving routes answer DAS samples with SLICED reads
-            # instead of a pure-host re-extension. Cache-only: any
-            # failure falls back to block_eds reconstruction.
-            try:
-                with tracing.span("node.extend_retention",
-                                  height=block.height):
-                    eds = self.app.extend_block(proposal.txs)
-                    self._eds_cache.put(block.height, eds)
-                self._persist_block_eds(block.height, eds)
-            except Exception as e:  # noqa: BLE001 — retention is a cache
-                log.warn("eds retention failed",
-                         error=f"{type(e).__name__}: {e}")
-
+        self.blocks[block.height] = block
         for i, raw in enumerate(proposal.txs):
             key = tx_hash(raw)
             self.mempool.remove(key)
             self.tx_index[key] = (block.height, i)
         self.mempool.evict_expired(self.app.height)
+        # (skip retention across an upgrade boundary: extend_block runs
+        # at the POST-commit version, the square was built at the
+        # pre-commit one — block_eds's versioned reconstruction governs)
+        retain = self.extend_blocks and build_version == self.app.app_version
+        eds = self._retain_block_eds(block) if retain else None
+        if self.home:
+            with tracing.span("node.persist", height=block.height):
+                self._store_block(block)
+                if eds is not None:
+                    self._persist_block_eds(block.height, eds)
+                elif retain:  # the height's EDS file is not written
+                    self._persist_failed(block.height, "retention")
         return block
 
+    def _retain_block_eds(self, block: Block):
+        """ExtendBlock retention: keep the committed square's EDS handle
+        (device-resident + lazy on the TPU backend) so the serving
+        routes answer DAS samples with SLICED reads instead of a
+        pure-host re-extension, and keep the DAH the same extend
+        computed, so neither the store nor /dah hashes the square again
+        on the host. Returns the EDS, or None when retention failed:
+        it is a cache, and the block falls back to block_eds
+        reconstruction. A square whose roots disagree with the
+        committed data_hash is an IntegrityError, counted in
+        retention_dah_mismatch_total, and is neither retained nor
+        stored."""
+        from celestia_tpu.integrity import IntegrityError
+        from celestia_tpu.telemetry import metrics
+
+        try:
+            with tracing.span("node.extend_retention", height=block.height):
+                eds, dah = self.app.extend_block(block.txs)
+                if dah.hash() != block.data_hash:
+                    metrics.incr_counter("retention_dah_mismatch_total")
+                    raise IntegrityError(
+                        f"retention extend of height {block.height} hashes "
+                        f"to {dah.hash().hex()}, the block commits to "
+                        f"{block.data_hash.hex()}")
+                self._eds_cache.put(block.height, eds)
+        except Exception as e:  # noqa: BLE001 — retention is a cache
+            log.warn("eds retention failed",
+                     error=f"{type(e).__name__}: {e}")
+            return None
+        self._dah_cache[block.height] = dah
+        # bounded: seeded heights age out (block_dah then reads the
+        # store's copy, or recomputes)
+        self._dah_cache.pop(block.height - self._DAH_SEED_HEIGHTS, None)
+        return eds
+
     def _store_block(self, block: Block) -> None:
-        self.blocks[block.height] = block
-        if self.home:
+        """The block record under home/blocks, written durable (tmp,
+        fsync, rename, directory fsync through the store's FsShim)
+        before produce_block returns: Node.load replays these files, so
+        an acknowledged height survives a power cut. Failures raise."""
+        from celestia_tpu import store as store_pkg
+
+        with tracing.span("node.persist.block_record",
+                          height=block.height) as sp:
+            raw = json.dumps(block.to_json()).encode()
             path = self.home / "blocks" / f"{block.height}.json"
-            path.write_text(json.dumps(block.to_json()))
+            store = self.store
+            store_pkg.write_durable(
+                path, raw, height=block.height,
+                fs=store_pkg._FS if store is None else store._fs,
+                durable=True if store is None else store.durable)
+            sp.set(bytes=len(raw))
 
     def _persist_block_eds(self, height: int, eds) -> None:
-        """Best-effort durable retention: write the committed square's
-        pages + served DAH (+ device row-tree levels when the square is
-        device-resident) to the BlockStore, so a restart serves this
-        height from disk with byte-identical DAH and provers. A failed
-        put degrades to reconstruction, never fails the block."""
+        """Durable retention: write the committed square's pages, its
+        DAH (the one retention seeded) and, when the square is
+        device-resident, its device row-tree levels to the BlockStore,
+        so a restart serves this height from disk with byte-identical
+        DAH and provers. Best effort — a failed put degrades to
+        reconstruction and never fails the block — but every failure is
+        counted in eds_persist_failures_total{reason} and logged."""
         if self.store is None:
+            self._persist_failed(height, "no_store")
+            return
+        import numpy as np
+
+        dah = self.block_dah(height)
+        if dah is None:
+            self._persist_failed(height, "no_dah")
             return
         try:
-            import numpy as np
-
-            dah = self.block_dah(height)
-            if dah is None:
-                return
-            levels = None
-            arr = getattr(eds, "device_data", None)
-            if arr is not None:
-                try:
+            with tracing.span("node.persist.fetch", height=height):
+                levels = None
+                if eds.device_data is not None:
                     from celestia_tpu.ops import extend_tpu
 
-                    levels = extend_tpu.eds_row_levels_device(arr)
-                except Exception:  # noqa: BLE001 — levels are optional
-                    levels = None
-            data = np.asarray(getattr(eds, "data", eds))
-            width = int(getattr(eds, "original_width",
-                                data.shape[0] // 2))
-            rpp = getattr(self._eds_cache, "rows_per_page", None) or 8
-            self.store.put_eds(height, data, width,
-                               dah_doc=dah.to_json(), levels=levels,
-                               rows_per_page=rpp)
+                    try:
+                        levels = extend_tpu.eds_row_levels_device(
+                            eds.device_data)
+                    except Exception as e:  # noqa: BLE001 — stored without
+                        self._persist_failed(height, "levels", e)
+                data = np.asarray(eds.data)
         except Exception as e:  # noqa: BLE001 — persistence is a cache
-            log.info("eds persistence failed", height=height,
-                     error=str(e))
+            self._persist_failed(height, "fetch", e)
+            return
+        rpp = getattr(self._eds_cache, "rows_per_page", None) or 8
+        try:
+            entry = self.store.put_eds(height, data, eds.original_width,
+                                       dah_doc=dah.to_json(),
+                                       levels=levels, rows_per_page=rpp)
+        except Exception as e:  # noqa: BLE001 — persistence is a cache
+            self._persist_failed(height, "write", e)
+            return
+        if entry is None:
+            self._persist_failed(height, "read_only")
+
+    @staticmethod
+    def _persist_failed(height: int, reason: str,
+                        error: Exception | None = None) -> None:
+        from celestia_tpu.telemetry import metrics
+
+        metrics.incr_counter("eds_persist_failures_total", reason=reason)
+        log.warn("eds persistence failed", height=height, reason=reason,
+                 error=f"{type(error).__name__}: {error}" if error else None)
 
     # --- the multi-chip block pipeline (specs/parallel.md) ---
 
@@ -554,6 +614,7 @@ class Node:
             [r.tobytes() for r in block.row_roots],
             [c.tobytes() for c in block.col_roots],
         )
+        # lint: allow(C005) reason=_dah_cache is a lock-free memo of immutable DAHs: only single-key get/set/pop (atomic under the GIL), never iterated, so a reader outside the node lock sees a whole DAH or none
         self._dah_cache[block.height] = dah
         if block.eds is not None:
             try:

@@ -97,7 +97,7 @@ import struct
 import threading
 import time
 
-from celestia_tpu import faults
+from celestia_tpu import faults, tracing
 from celestia_tpu.integrity import IntegrityError, crc32c, record_sdc
 from celestia_tpu.log import logger
 from celestia_tpu.telemetry import metrics
@@ -205,7 +205,9 @@ class FsShim:
 
     def fsync(self, f, *, path, **ctx) -> None:
         faults.fire("store.fsync", path=str(path), **ctx)
-        os.fsync(f.fileno())
+        with tracing.span("store.fsync"):
+            os.fsync(f.fileno())
+        metrics.incr_counter("store_sync_total", kind="fsync")
 
     def replace(self, src, dst, **ctx) -> None:
         faults.fire("store.rename", src=str(src), dst=str(dst), **ctx)
@@ -213,11 +215,13 @@ class FsShim:
 
     def dirsync(self, dirpath, **ctx) -> None:
         faults.fire("store.dirsync", path=str(dirpath), **ctx)
-        fd = os.open(dirpath, os.O_RDONLY)
-        try:
-            os.fsync(fd)
-        finally:
-            os.close(fd)
+        with tracing.span("store.dirsync"):
+            fd = os.open(dirpath, os.O_RDONLY)
+            try:
+                os.fsync(fd)
+            finally:
+                os.close(fd)
+        metrics.incr_counter("store_sync_total", kind="dirsync")
 
     def unlink(self, path, *, missing_ok: bool = True, **ctx) -> None:
         faults.fire("store.unlink", path=str(path), **ctx)
@@ -225,6 +229,49 @@ class FsShim:
 
 
 _FS = FsShim()
+
+
+def write_durable(path, parts, *, fs: FsShim = _FS, durable: bool = True,
+                  truncate=None, **ctx) -> None:
+    """Land one whole file so that a crash leaves the old file or the
+    new one under ``path``, never a torn one: write ``parts`` (bytes,
+    or an iterable of bytes) to a `.tmp` sibling, fsync it, rename it
+    over ``path``, fsync the directory (specs/store.md §Durability
+    contract). ``durable=False`` keeps the tmp+rename and skips both
+    syncs. ``truncate`` (a `store.write` short_write fault) lands only a
+    prefix of the body and fails like a real torn write. On any failure
+    the `.tmp` is unlinked and the error re-raised."""
+    if isinstance(parts, (bytes, bytearray, memoryview)):
+        parts = (parts,)
+    path = pathlib.Path(path)
+    tmp = path.with_name(path.name + ".tmp")
+    try:
+        with fs.open_w(tmp, **ctx) as f:
+            if truncate is not None:
+                f.write(truncate(b"".join(parts)))
+                f.flush()
+                err = faults.DiskFault(
+                    errno.EIO, f"short write persisting {path.name}")
+                err.short_write = True
+                raise err
+            for part in parts:
+                f.write(part)
+            f.flush()
+            if durable:
+                fs.fsync(f, path=tmp, **ctx)
+        fs.replace(tmp, path, **ctx)
+        if durable:
+            # the rename itself is not crash-durable until the parent
+            # directory's entry is (ADR-026): without this dirsync an
+            # acknowledged file can vanish after power loss — the bug
+            # the powercut explorer finds
+            fs.dirsync(path.parent, **ctx)
+    except BaseException:
+        try:
+            fs.unlink(tmp, missing_ok=True, **ctx)
+        except (OSError, faults.FaultError):
+            pass  # disk too sick to even unlink; reindex ignores .tmp
+        raise
 
 
 class BlockStore:
@@ -282,6 +329,13 @@ class BlockStore:
         (ENOSPC degradation) skips the put and returns None — except
         one put per ``reprobe_interval_s``, which runs as the recovery
         probe and clears the degradation if it lands."""
+        with tracing.span("store.put_eds", height=height):
+            return self._put_eds(height, eds_np, original_width,
+                                 dah_doc=dah_doc, levels=levels,
+                                 rows_per_page=rows_per_page)
+
+    def _put_eds(self, height: int, eds_np, original_width: int, *,
+                 dah_doc: dict, levels, rows_per_page: int):
         import numpy as np
 
         # lint: allow(C005) reason=_read_only is a GIL-atomic bool read; transitions serialize under _index_lock and a one-read-stale value only delays (never corrupts) a single put
@@ -322,16 +376,15 @@ class BlockStore:
             "levels_crc": crc32c(levels_bytes), "page_slot": page_slot,
         }
         path = self.root / f"{height}{SUFFIX}"
-        tmp = self.root / f"{height}{SUFFIX}.tmp"
         try:
             # the write drill: corrupt/bitflip rules mangle the first
             # page payload AFTER its CRC was computed — rot-on-disk
             # that the next read MUST catch. Fired before any bytes
             # land, INSIDE the abort scope, so enospc/error strikes
-            # count as aborted puts and clean up like the real thing.
-            # A short_write rule returns a truncator instead: only a
-            # seeded prefix of the file body lands, then the put fails
-            # like a real torn write.
+            # count as aborted puts like the real thing. A short_write
+            # rule returns a truncator instead: only a seeded prefix of
+            # the file body lands, then the put fails like a real torn
+            # write.
             truncate = None
             flip = faults.fire("store.write", height=height,
                                pages=page_count)
@@ -343,29 +396,10 @@ class BlockStore:
             for payload, crc in pages:
                 parts.append(_RECORD.pack(len(payload), crc, 0))
                 parts.append(payload.ljust(page_slot, b"\x00"))
-            with self._fs.open_w(tmp, height=height) as f:
-                if truncate is not None:
-                    f.write(truncate(b"".join(parts)))
-                    f.flush()
-                    err = faults.DiskFault(
-                        errno.EIO,
-                        f"short write persisting height {height}")
-                    err.short_write = True
-                    raise err
-                for part in parts:
-                    f.write(part)
-                f.flush()
-                if self.durable:
-                    self._fs.fsync(f, path=tmp, height=height)
-            self._fs.replace(tmp, path, height=height)
-            if self.durable:
-                # the rename itself is not crash-durable until the
-                # parent directory's entry is (ADR-026): without this
-                # dirsync an acknowledged height can vanish after
-                # power loss — the bug the powercut explorer finds
-                self._fs.dirsync(self.root, height=height)
+            write_durable(path, parts, fs=self._fs, durable=self.durable,
+                          truncate=truncate, height=height)
         except Exception as exc:
-            self._abort_put(tmp, exc, height)
+            self._abort_put(exc)
             raise
         if self._read_only:  # this put was the recovery probe, and won
             self._exit_read_only()
@@ -374,14 +408,15 @@ class BlockStore:
             self._index[height] = entry
             self._puts += 1
         metrics.incr_counter("store_put_total")
+        metrics.incr_counter("store_put_bytes_total",
+                             entry.page_offset(entry.page_count))
         self._publish()
         return entry
 
-    def _abort_put(self, tmp: pathlib.Path, exc: BaseException,
-                   height: int) -> None:
-        """Mid-put failure: classify + count the abort, best-effort
-        unlink the `.tmp` orphan (instead of leaving it for the next
-        reindex), and flip read-only on ENOSPC."""
+    def _abort_put(self, exc: BaseException) -> None:
+        """Mid-put failure (`write_durable` has already unlinked the
+        `.tmp`): classify + count the abort, and flip read-only on
+        ENOSPC."""
         if getattr(exc, "short_write", False):
             reason = "short_write"
         elif isinstance(exc, OSError) and exc.errno == errno.ENOSPC:
@@ -393,10 +428,6 @@ class BlockStore:
             self._put_aborts += 1
         metrics.incr_counter("store_write_error_total")
         metrics.incr_counter("store_put_aborted_total", reason=reason)
-        try:
-            self._fs.unlink(tmp, missing_ok=True, height=height)
-        except (OSError, faults.FaultError):
-            pass  # disk too sick to even unlink; reindex ignores .tmp
         if reason == "enospc":
             self._enter_read_only("enospc")
 

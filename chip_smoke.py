@@ -291,7 +291,7 @@ def phase_repair(k: int, seed: int) -> dict:
     check(square_pkg.square_size(len(data_square)) == k,
           f"repair square is not {k} x {k}")
     t0 = time.perf_counter()
-    eds = app.extend_block(txs)
+    eds, _dah = app.extend_block(txs)
     dah = app._proposal_dah(data_square)
     extend_wall = time.perf_counter() - t0
     check(eds.device_data is not None, "extend_block EDS is not on device")

@@ -53,8 +53,9 @@ class TestNode:
         found = node.get_tx(tx_hash(block.txs[0]))
         assert found is not None
 
-        square = node.app.extend_block(block.txs)
+        square, dah = node.app.extend_block(block.txs)
         assert square.width >= 2
+        assert dah.hash() == block.data_hash
 
     def test_mempool_priority_order(self):
         node = new_node()
@@ -309,6 +310,167 @@ class TestStateSync:
         assert recovered.app.height == 4
         assert recovered.produce_block(90.0).app_hash == \
             node.produce_block(90.0).app_hash
+
+
+def _pfb_for_square(k: int, seq: int = 0) -> bytes:
+    """A signed one-blob PFB (ALICE, account 1) whose square is k x k."""
+    from celestia_tpu.tx import Fee, sign_tx
+    from celestia_tpu.x.blob.types import estimate_gas, new_msg_pay_for_blobs
+
+    size = 478 * (k * k // 4 + 1) if k > 2 else 100
+    b = blob_pkg.new_blob(ns.new_v0(b"dah-seed"), bytes([k]) * size, 0)
+    gas = estimate_gas([size])
+    tx = sign_tx(ALICE, [new_msg_pay_for_blobs(ALICE.bech32_address(), b)],
+                 "test", 1, seq, Fee(amount=gas, gas_limit=gas))
+    return blob_pkg.marshal_blob_tx(tx.marshal(), [b])
+
+
+def durable_device_node(home) -> Node:
+    """A home-backed node on the device backend with ExtendBlock
+    retention on (the CPU stands in for the chip)."""
+    node = new_node(home, extend_backend="tpu")
+    node.extend_blocks = True
+    return node
+
+
+class TestDurableHome:
+    """A validator with a home directory: retention keeps the DAH its
+    extend computed, the block record is durable, and every persist
+    failure is counted (specs/store.md §Block record)."""
+
+    @pytest.mark.parametrize("k", [2, 4, 8, 16, 32])
+    def test_retention_seeds_the_host_dah(self, k):
+        import numpy as np
+
+        from celestia_tpu import appconsts, da
+        from celestia_tpu import square as square_pkg
+        from celestia_tpu.node.node import Block
+        from celestia_tpu.shares import to_bytes
+
+        node = Node(App(extend_backend="tpu"), extend_blocks=True)
+        txs = [_pfb_for_square(k)]
+        square = square_pkg.construct(
+            txs, node.app.app_version,
+            appconsts.square_size_upper_bound(node.app.app_version))
+        assert square_pkg.square_size(len(square)) == k
+        host = da.new_data_availability_header(da.extend_shares(to_bytes(square)))
+        block = Block(height=7, time=0.0, txs=txs, square_size=k,
+                      data_hash=host.hash(), app_hash=b"")
+        eds = node._retain_block_eds(block)
+        assert eds.device_data is not None
+        assert node._dah_cache[7] == host
+        fetched = da.ExtendedDataSquare(np.asarray(eds.data), k)
+        assert da.new_data_availability_header(fetched) == node._dah_cache[7]
+        assert node.block_dah(7) is node._dah_cache[7]
+
+    def test_retained_square_that_disagrees_is_dropped(self, tmp_path,
+                                                       monkeypatch):
+        from celestia_tpu import da
+        from celestia_tpu.telemetry import metrics
+
+        node = durable_device_node(tmp_path)
+        extend = node.app.extend_block
+
+        def other_roots(txs):
+            eds, dah = extend(txs)
+            return eds, da.DataAvailabilityHeader(dah.row_roots[::-1],
+                                                  dah.column_roots)
+        monkeypatch.setattr(node.app, "extend_block", other_roots)
+        signer = Signer.setup_single(ALICE, node)
+        b = blob_pkg.new_blob(ns.new_v0(b"mismatch"), b"\x31" * 3000, 0)
+        assert signer.submit_pay_for_blob([b]).code == 0
+        before = metrics.get_counter("retention_dah_mismatch_total")
+        block = node.produce_block(30.0)
+        h = block.height
+        assert metrics.get_counter("retention_dah_mismatch_total") == before + 1
+        assert h not in node._dah_cache and node._eds_cache.get(h) is None
+        # the committed block is recorded all the same, its square is not
+        assert (tmp_path / "blocks" / f"{h}.json").exists()
+        assert h not in node.store and len(node.mempool) == 0
+
+    @pytest.mark.parametrize("cause", ["device_error", "dah_mismatch"])
+    def test_failed_retention_counts_a_persist_failure(self, tmp_path,
+                                                      monkeypatch, cause):
+        from celestia_tpu import da
+        from celestia_tpu.telemetry import metrics
+
+        node = durable_device_node(tmp_path)
+        extend = node.app.extend_block
+
+        def failing(txs):
+            if cause == "device_error":
+                raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+            eds, dah = extend(txs)
+            return eds, da.DataAvailabilityHeader(dah.row_roots[::-1],
+                                                  dah.column_roots)
+        monkeypatch.setattr(node.app, "extend_block", failing)
+        signer = Signer.setup_single(ALICE, node)
+        b = blob_pkg.new_blob(ns.new_v0(b"noretain"), b"\x32" * 3000, 0)
+        assert signer.submit_pay_for_blob([b]).code == 0
+        before = metrics.get_counter("eds_persist_failures_total",
+                                     reason="retention")
+        h = node.produce_block(30.0).height
+        assert metrics.get_counter("eds_persist_failures_total",
+                                   reason="retention") == before + 1
+        assert h not in node.store
+        assert (tmp_path / "blocks" / f"{h}.json").exists()
+
+    def test_home_block_hashes_no_square_on_host(self, tmp_path):
+        from celestia_tpu import tracing
+        from celestia_tpu.telemetry import metrics
+
+        node = durable_device_node(tmp_path)
+        node.save_snapshot()
+        signer = Signer.setup_single(ALICE, node)
+        b = blob_pkg.new_blob(ns.new_v0(b"durable"), b"\x44" * 6000, 0)
+        assert signer.submit_pay_for_blob([b]).code == 0
+        roots = metrics.get_counter("host_nmt_roots_total")
+        put_bytes = metrics.get_counter("store_put_bytes_total")
+        tracing.reset()
+        tracing.enable()
+        try:
+            with tracing.record() as rec:
+                block = node.produce_block(30.0)
+                assert node.block_dah(block.height).hash() == block.data_hash
+        finally:
+            tracing.reset()
+        assert metrics.get_counter("host_nmt_roots_total") == roots
+        entry = node.store.entry(block.height)
+        assert entry.levels_len > 0
+        assert metrics.get_counter("store_put_bytes_total") - put_bytes == \
+            entry.path.stat().st_size
+        spans = {s.span_id: s for s in rec.spans}
+        parent = {s.name: spans.get(s.parent_id) for s in rec.spans}
+        (persist,) = [s for s in rec.spans if s.name == "node.persist"]
+        assert spans[persist.parent_id].name == "node.apply_block"
+        for child in ("node.persist.block_record", "node.persist.fetch",
+                      "store.put_eds"):
+            assert parent[child] is persist
+        syncs = [parent_of.name for s in rec.spans
+                 if s.name in ("store.fsync", "store.dirsync")
+                 for parent_of in [spans[s.parent_id]]]
+        assert sorted(syncs) == ["node.persist.block_record"] * 2 + \
+            ["store.put_eds"] * 2
+        # a restart serves the stored DAH, again without host hashing
+        resumed = Node.load(str(tmp_path), extend_backend="tpu")
+        assert resumed.latest_height() == block.height
+        assert resumed.block_dah(block.height).hash() == block.data_hash
+
+    def test_injected_store_write_error_is_counted(self, tmp_path):
+        from celestia_tpu import faults
+        from celestia_tpu.telemetry import metrics
+
+        node = durable_device_node(tmp_path)
+        before = metrics.get_counter("eds_persist_failures_total",
+                                     reason="write")
+        with faults.inject(faults.rule("store.write", "error"), seed=29):
+            block = node.produce_block(30.0)
+        assert metrics.get_counter("eds_persist_failures_total",
+                                   reason="write") == before + 1
+        assert block.height not in node.store
+        # the block record does not go through store.write
+        assert (tmp_path / "blocks" / f"{block.height}.json").exists()
+        assert node.block_dah(block.height).hash() == block.data_hash
 
 
 class TestExtendBackend:

@@ -322,3 +322,60 @@ class TestFormatConstants:
             assert e.page_offset(i) == e.page_base + i * (
                 RECORD_HEADER_SIZE + e.page_slot)
             assert e.page_rows(i) == 2
+
+
+class TestWholeFileWrites:
+    """`write_durable`, the path of the block record and of every put
+    (specs/store.md §Block record) — tmp, fsync, rename, dirsync through
+    the store's FsShim, and a `.tmp` that never outlives a failure."""
+
+    def test_durable_sequence_through_the_shim(self, tmp_path):
+        from celestia_tpu.store import powercut, write_durable
+
+        rec = powercut.RecordingFs()
+        target = tmp_path / "3.json"
+        write_durable(target, b'{"height": 3}', fs=rec, height=3)
+        assert [(e.kind, e.path or e.dst) for e in rec.trace] == [
+            ("open", "3.json.tmp"), ("write", "3.json.tmp"),
+            ("fsync", "3.json.tmp"), ("rename", "3.json"),
+            ("dirsync", ".")]
+        assert target.read_bytes() == b'{"height": 3}'
+        assert not (tmp_path / "3.json.tmp").exists()
+
+    def test_non_durable_store_keeps_the_rename_only(self, tmp_path):
+        from celestia_tpu.store import powercut, write_durable
+
+        rec = powercut.RecordingFs()
+        write_durable(tmp_path / "3.json", b"{}", fs=rec, durable=False)
+        assert [e.kind for e in rec.trace] == ["open", "write", "rename"]
+
+    @pytest.mark.parametrize("durable", [True, False])
+    def test_sync_counter_counts_each_sync(self, tmp_path, durable):
+        from celestia_tpu.store import write_durable
+
+        def syncs():
+            return [metrics.get_counter("store_sync_total", kind=kind)
+                    for kind in ("fsync", "dirsync")]
+        before = syncs()
+        write_durable(tmp_path / "3.json", b"{}", durable=durable)
+        assert [a - b for a, b in zip(syncs(), before)] == [int(durable)] * 2
+
+    def test_failed_sync_leaves_the_old_file_and_no_tmp(self, tmp_path):
+        from celestia_tpu.store import write_durable
+
+        target = tmp_path / "3.json"
+        target.write_bytes(b"old")
+        with faults.inject(faults.rule("store.fsync", "fsync_fail"),
+                           seed=CHAOS_SEED):
+            with pytest.raises(OSError):
+                write_durable(target, b"new")
+        assert target.read_bytes() == b"old"
+        assert list(tmp_path.iterdir()) == [target]
+
+    def test_put_bytes_counter_is_the_file_size(self, tmp_path):
+        store = BlockStore(tmp_path)
+        before = metrics.get_counter("store_put_bytes_total")
+        _put(store, 1, levels=[np.zeros((W, 1, 90), np.uint8)])
+        _put(store, 2)
+        sizes = sum(store.entry(h).path.stat().st_size for h in (1, 2))
+        assert metrics.get_counter("store_put_bytes_total") - before == sizes
